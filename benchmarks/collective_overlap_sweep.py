@@ -58,7 +58,7 @@ def _measure(cfgv):
     from repro.core.roofline import parse_collectives
     from repro.models import layers as L
     from repro.parallel.collectives import bucketed_grad_sync
-    from repro.parallel.jaxcompat import make_mesh, set_mesh, shard_map
+    from repro.parallel.jaxcompat import make_mesh, shard_map
 
     m = MESH_M
     d, ff = cfgv["d_model"], cfgv["d_ff"]
@@ -114,7 +114,7 @@ def _measure(cfgv):
     # re-gather(x) for dW — 5 rings of (m-1)/m * |x| per layer
     expected_ring_wire = LAYERS * 5 * (m - 1) / m * x_bytes
     points = []
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lanes = [("gspmd", None, lambda: gspmd_mlp)]
         lanes += [(f"overlapped", c, lambda c=c: overlapped_mlp(c))
                   for c in cfgv["chunk_sweep"]]
@@ -141,24 +141,11 @@ def _measure(cfgv):
                 assert (0.75 * expected_ring_wire <= stats.wire_bytes
                         <= expected_ring_wire + 1024), \
                     (stats.wire_bytes, expected_ring_wire, stats.ops)
-                from repro.core.roofline import (_GROUPS_IOTA_RE,
-                                                 _GROUPS_LIST_RE,
-                                                 _tensor_bytes)
+                from repro.core.roofline import _group_size, _tensor_bytes
                 chunk_bytes = x_bytes // m
-
-                def group_size(ln):
-                    g = _GROUPS_IOTA_RE.search(ln)
-                    if g:
-                        return int(g.group(2))
-                    g = _GROUPS_LIST_RE.search(ln)
-                    if g:
-                        return len([s for s in g.group(1).split(",")
-                                    if s.strip()])
-                    return m
-
                 mono = [ln for ln in stats.lines
                         if ("all-reduce" in ln or "all-gather" in ln)
-                        and group_size(ln) > 1
+                        and _group_size(ln, m) > 1
                         and _tensor_bytes(ln) >= chunk_bytes]
                 assert not mono, mono
             points.append(pt)
@@ -179,7 +166,7 @@ def _measure(cfgv):
     dmesh = make_mesh((m, 1), ("data", "model"))
     grad_bytes = sum(p.size * 4 for lp in params for p in lp.values())
     dp_points = []
-    with set_mesh(dmesh):
+    with jax.set_mesh(dmesh):
         dp_sh = [{"wi": NamedSharding(dmesh, P()),
                   "wo": NamedSharding(dmesh, P())} for _ in range(LAYERS)]
         bx_sh = NamedSharding(dmesh, P("data"))

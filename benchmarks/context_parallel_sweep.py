@@ -55,10 +55,10 @@ def _measure(cfgv, check_time: bool):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.core.roofline import (_GROUPS_IOTA_RE, _GROUPS_LIST_RE,
-                                     _tensor_bytes, parse_collectives)
+    from repro.core.roofline import (_group_size, _tensor_bytes,
+                                     parse_collectives)
     from repro.parallel.context import gathered_attention, ring_attention
-    from repro.parallel.jaxcompat import make_mesh, set_mesh, shard_map
+    from repro.parallel.jaxcompat import make_mesh, shard_map
 
     m = MESH_M
     b, t = cfgv["batch"], cfgv["seq"]
@@ -99,17 +99,8 @@ def _measure(cfgv, check_time: bool):
     wire_lo = 3 * (m - 1) * pair_bytes
     wire_hi = (m - 1) * 2 * pair_bytes + 2 * m * pair_bytes
 
-    def group_size(ln):
-        g = _GROUPS_IOTA_RE.search(ln)
-        if g:
-            return int(g.group(2))
-        g = _GROUPS_LIST_RE.search(ln)
-        if g:
-            return len([s for s in g.group(1).split(",") if s.strip()])
-        return m
-
     points = {}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for lane, attn in (("gathered", gathered_attention),
                            ("ring", ring_attention)):
             fn = jax.jit(jax.value_and_grad(lane_loss(attn),
@@ -129,7 +120,7 @@ def _measure(cfgv, check_time: bool):
                 # KV shard (scalar loss psums are fine)
                 mono = [ln for ln in stats.lines
                         if ("all-gather" in ln or "all-reduce" in ln)
-                        and group_size(ln) > 1
+                        and _group_size(ln, m) > 1
                         and _tensor_bytes(ln) >= pair_bytes // 2]
                 assert not mono, mono
             else:

@@ -67,7 +67,7 @@ def _measure(reps: int, warmup: int):
     import jax
     import jax.numpy as jnp
 
-    from repro.parallel.jaxcompat import make_mesh, set_mesh
+    from repro.parallel.jaxcompat import make_mesh
     from repro.parallel.pipeline import (make_schedule, pipeline_apply,
                                          pipeline_value_and_grad,
                                          plan_scheduled_runtime,
@@ -123,7 +123,7 @@ def _measure(reps: int, warmup: int):
 
         rtp = plan_scheduled_runtime(sched)
         lanes = {}
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for name, fn in (("ad", ad_step), ("scheduled", sched_step)):
                 compiled = jax.jit(fn).lower(stacked, x).compile()
                 ma = compiled.memory_analysis()

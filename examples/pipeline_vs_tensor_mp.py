@@ -14,7 +14,7 @@ import jax.numpy as jnp  # noqa: E402
 from repro.configs import get_config  # noqa: E402
 from repro.core.roofline import parse_collectives  # noqa: E402
 from repro.models import build_model  # noqa: E402
-from repro.parallel.jaxcompat import make_mesh, set_mesh  # noqa: E402
+from repro.parallel.jaxcompat import make_mesh  # noqa: E402
 from repro.parallel.pipeline import pipeline_apply, stack_to_stages  # noqa: E402
 from repro.parallel.plan import ParallelPlan  # noqa: E402
 from repro.parallel.sharding import ShardingRules  # noqa: E402
@@ -38,7 +38,7 @@ mesh = make_mesh((2, 4), ("data", "model"))
 rules = ShardingRules(cfg, mesh, ParallelPlan())
 p_sh = rules.params_shardings(jax.eval_shape(api.init, key))
 b_sh = rules.batch_shardings(jax.eval_shape(lambda: batch))
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     f = jax.jit(lambda p, b: api.loss_fn(p, b)[0], in_shardings=(p_sh, b_sh))
     lowered = f.lower(params, batch)
     tp_loss = f(params, batch)
@@ -70,7 +70,7 @@ def pipeline_loss(params, batch):
     return cross_entropy(logits, batch["labels"], cfg.vocab_size)
 
 
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     g = jax.jit(pipeline_loss)
     lowered_p = g.lower(params, batch)
     pp_loss = g(params, batch)

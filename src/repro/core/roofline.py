@@ -83,7 +83,7 @@ class CollectiveStats:
     ops: Dict[str, int]
     wire_bytes: float            # per-chip bytes on the wire (ring model)
     tensor_bytes: float          # raw summed tensor bytes (reported too)
-    lines: List[str]
+    lines: List[str]             # whole HLO lines of the collectives
 
     def to_dict(self):
         return {"ops": self.ops, "wire_bytes": self.wire_bytes,
@@ -125,7 +125,7 @@ def parse_collectives(hlo_text: str, default_group: int,
         ops[op] = ops.get(op, 0) + mult
         raw += tb * mult
         wire += tb * _wire_factor(op, g) * mult
-        lines_kept.append(ls[:200])
+        lines_kept.append(ls)
     return CollectiveStats(ops=ops, wire_bytes=wire, tensor_bytes=raw,
                            lines=lines_kept)
 

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ops import tpu_compiler_params
-
 
 def _lstm_kernel(x_ref, h_ref, c_ref, wx_ref, wh_ref, b_ref, hn_ref, cn_ref):
     x = x_ref[...].astype(jnp.float32)            # (bb, d_in)
@@ -77,7 +75,7 @@ def lstm_cell(x, h, c, wx, wh, b, *, block_b: int = 128, block_h: int = 128,
         ],
         out_specs=[pl.BlockSpec((bb, bh), lambda bi, hi: (bi, hi))] * 2,
         out_shape=out_shape,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(x, h, c, wx, wh, b)

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ops import tpu_compiler_params
-
 
 def _gmm_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_d: int):
     di = pl.program_id(3)
@@ -63,7 +61,7 @@ def gmm(x, w, *, block_c: int = 128, block_f: int = 128, block_d: int = 512,
         out_specs=pl.BlockSpec((1, bc, bf), lambda gi, ci, fi, di: (gi, ci, fi)),
         out_shape=jax.ShapeDtypeStruct((g, c + pc, f + pf), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

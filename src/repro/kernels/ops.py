@@ -11,22 +11,6 @@ import functools
 import os
 
 import jax
-import jax.numpy as jnp
-
-
-def tpu_compiler_params(**kwargs):
-    """Compat shim: the Pallas-TPU params class is ``TPUCompilerParams`` on
-    older jax releases and ``CompilerParams`` on newer ones.  Kernels call
-    this instead of naming either class so both jax versions work.
-
-    NOTE: defined before the kernel-module imports below on purpose — the
-    kernel modules import it from here at module scope, which only resolves
-    during a circular import if the name already exists.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "TPUCompilerParams", None) or pltpu.CompilerParams
-    return cls(**kwargs)
-
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import lstm_cell as _lstm

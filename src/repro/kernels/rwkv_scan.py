@@ -19,8 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ops import tpu_compiler_params
-
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
                 chunk: int):
@@ -38,7 +36,13 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
     S = s_ref[...]                            # (hd_k, hd_v)
 
     logw = jnp.log(jnp.maximum(w, 1e-38))
-    cum = jnp.cumsum(logw, axis=0)            # inclusive (chunk, hd)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive cumsum over the chunk as a lower-triangular matmul: Mosaic
+    # has no cumsum lowering; HIGHEST keeps the f32 sum off bf16 passes
+    cum = jax.lax.dot(jnp.where(ii >= jj, 1.0, 0.0), logw,
+                      precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)   # (chunk, hd)
     cume = cum - logw                         # exclusive
     total = cum[-1:, :]                       # (1, hd)
 
@@ -50,8 +54,6 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_ref, *,
     bmat = k * jnp.exp(-cum)
     scores = jax.lax.dot_general(a, bmat, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     scores = jnp.where(ii > jj, scores, 0.0)
     diag = jnp.sum(r * u * k, axis=1, keepdims=True)      # (chunk, 1)
     intra = jax.lax.dot(scores, v, preferred_element_type=jnp.float32) \
@@ -94,7 +96,7 @@ def wkv6(r, k, v, w, u, *, chunk: int = 128, interpret: bool = False):
         out_specs=pl.BlockSpec((1, chunk, hd), lambda bh, ci: (bh, ci, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, t, hd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hd, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rr, kr, vr, wr, ur)

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config
 from repro.core import roofline as rl
 from repro.launch.mesh import make_production_mesh
-from repro.parallel.jaxcompat import cost_analysis, set_mesh
 from repro.models.api import build_model, make_input_specs
 from repro.optim import adafactor, adamw, constant_lr
 from repro.parallel.plan import ParallelPlan
@@ -227,7 +226,7 @@ def analyze_combo(arch: str, shape_name: str, *, multi_pod: bool,
               f"resid@runtime={resid:.1f}", flush=True)
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, args = build_step(cfg, shape, mesh, plan, unroll=False)
         lowered = jitted.lower(*args)
         compiled = lowered.compile()
@@ -243,7 +242,7 @@ def analyze_combo(arch: str, shape_name: str, *, multi_pod: bool,
         "hbm_per_chip": rl.HBM_PER_CHIP,
     }
     rec["fits"] = rec["memory"]["peak_bytes"] <= rl.HBM_PER_CHIP
-    ca = cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     rec["real_cost"] = {"flops": ca.get("flops", 0.0),
                         "bytes": ca.get("bytes accessed", 0.0)}
     coll_real = rl.parse_collectives(compiled.as_text(), default_group=chips)
@@ -254,11 +253,11 @@ def analyze_combo(arch: str, shape_name: str, *, multi_pod: bool,
         costs = {}
         for nl in (1, 2):
             cfg_n = _unrolled_variant(cfg, nl)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 j, a = build_step(cfg_n, shape, mesh, plan, unroll=unroll_analysis)
                 low = j.lower(*a)
                 comp = low.compile()
-            c = cost_analysis(comp)
+            c = comp.cost_analysis()
             coll = rl.parse_collectives(comp.as_text(), default_group=chips)
             costs[nl] = {"flops": c.get("flops", 0.0),
                          "bytes": c.get("bytes accessed", 0.0),

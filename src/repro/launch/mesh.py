@@ -1,9 +1,8 @@
 """Production mesh builders.
 
 Functions, not module-level constants — importing this module never touches
-jax device state (the dry-run sets XLA_FLAGS before any jax init).  Mesh
-construction goes through ``parallel.jaxcompat`` so both old and new jax
-releases work.
+jax device state (the dry-run sets XLA_FLAGS before any jax init).  Meshes
+come from ``parallel.jaxcompat.make_mesh`` (``Auto`` axis types).
 """
 from __future__ import annotations
 

@@ -13,11 +13,17 @@ tick on a dp x tp mesh (forced host devices work for CPU smoke runs)::
         --batch 8 --prompt-len 32 --max-new 16
 
 Multi-replica with fault injection (``serve.router.ReplicaRouter``:
-least-loaded dispatch, health-checked failover, bounded queues)::
+least-loaded dispatch, health-checked failover, bounded queues).  Replica
+``r`` runs on devices ``[r*tp, (r+1)*tp)``, so ``--replicas N --tp T``
+needs N*T devices (chips, or forced host devices on CPU)::
 
+    XLA_FLAGS=--xla_force_host_platform_device_count=2 \
     python -m repro.launch.serve --arch llama3_2_1b --reduced \
         --continuous --replicas 2 --slots 4 --max-queue 16 \
         --fault "kill@5:0" --batch 8 --prompt-len 32 --max-new 16
+
+``main(argv)`` returns what it served (see its docstring), so a script can
+drive it in-process.
 """
 from __future__ import annotations
 
@@ -28,12 +34,18 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.api import build_model
 from repro.serve.continuous import ContinuousEngine, Request
 from repro.serve.engine import ServeEngine
 
 
-def main():
+def main(argv=None) -> dict:
+    """Run the serving CLI on ``argv`` (default ``sys.argv[1:]``).  Returns
+    ``prompts`` (int32 (batch, prompt_len)), ``params``, ``wall_s`` and
+    ``n_tokens``; the continuous paths add ``results`` (``RequestResult``s
+    by rid) and ``engines`` (one ``ContinuousEngine`` per replica), the
+    router path its ``stats``, the static path the generated ``tokens``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -54,7 +66,8 @@ def main():
                     "device_count=N on CPU)")
     ap.add_argument("--replicas", type=int, default=1,
                     help="independent continuous-engine replica groups "
-                    "behind the fault-tolerant router (tp devices each)")
+                    "behind the fault-tolerant router, each on its own tp "
+                    "devices")
     ap.add_argument("--fault", default="",
                     help="replica-keyed fault schedule, e.g. "
                     "'kill@5:0, stall@7:1:0.5, nanlogits@9:0'")
@@ -65,7 +78,10 @@ def main():
                     help="router health watchdog seconds (0 = off). Leave "
                     "off on cold CPU runs: every distinct prefill-chunk "
                     "shape retraces for seconds and reads as a stall")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[device] {dev.platform} {dev.device_kind} x{jax.device_count()}")
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -83,23 +99,11 @@ def main():
                         max_new_tokens=args.max_new)
                 for i in range(args.batch)]
         if args.replicas > 1 or args.fault or args.max_queue:
-            import numpy as np
-            from repro.serve.router import ReplicaRouter
+            from repro.serve.router import ReplicaRouter, replica_meshes
             from repro.train.fault import parse_fault_schedule
-            meshes = model_axis = None
-            batch_axes = ()
-            if args.tp > 1:
-                devs = jax.devices()
-                need = args.replicas * args.tp
-                if need > len(devs):
-                    raise SystemExit(
-                        f"--replicas {args.replicas} x --tp {args.tp} needs "
-                        f"{need} devices, only {len(devs)} visible")
-                meshes = [jax.sharding.Mesh(
-                    np.asarray(devs[r * args.tp:(r + 1) * args.tp]
-                               ).reshape(1, args.tp), ("data", "model"))
-                    for r in range(args.replicas)]
-                model_axis, batch_axes = "model", ("data",)
+            meshes = replica_meshes(args.replicas, args.tp)
+            model_axis, batch_axes = (("model", ("data",)) if args.tp > 1
+                                      else (None, ()))
             router = ReplicaRouter(
                 api, params, replicas=args.replicas, n_slots=args.slots,
                 capacity=capacity, prefill_chunk=args.prefill_chunk,
@@ -122,7 +126,10 @@ def main():
                   f"failovers={router.stats['failovers']}, "
                   f"states={router.replica_states})")
             print("first sequence:", results[0].tokens)
-            return
+            return {"prompts": tokens, "params": params, "wall_s": dt,
+                    "n_tokens": toks, "results": results,
+                    "engines": [r.engine for r in router.replicas],
+                    "stats": dict(router.stats)}
         mesh = model_axis = None
         if args.tp > 1:
             from repro.parallel.jaxcompat import make_mesh
@@ -144,7 +151,8 @@ def main():
         print(f"[serve] continuous: {toks} tokens in {dt:.2f}s "
               f"({toks / dt:.1f} tok/s, slots={args.slots}, tp={args.tp})")
         print("first sequence:", results[0].tokens)
-        return
+        return {"prompts": tokens, "params": params, "wall_s": dt,
+                "n_tokens": toks, "results": results, "engines": [engine]}
 
     engine = ServeEngine(api, params, temperature=args.temperature)
     batch = {"tokens": tokens}
@@ -162,6 +170,8 @@ def main():
     print(f"[serve] {toks} tokens in {dt:.2f}s "
           f"({toks / dt:.1f} tok/s, batch={args.batch})")
     print("first sequence:", res.tokens[0].tolist())
+    return {"prompts": tokens, "params": params, "wall_s": dt,
+            "n_tokens": toks, "tokens": res.tokens}
 
 
 if __name__ == "__main__":

@@ -18,9 +18,10 @@ budget (``--devices``, default 256) — and *executes* the winning plan:
 pipeline plans run through ``parallel.pipeline.pipeline_apply`` on a
 **dp x stages mesh** — the model axis carries the stages, the data axis
 carries as much of the projected DP degree as the local machine affords
-(capped by ``--max-local-devices``, default 8, on CPU), with the batch
+(capped by ``--max-local-devices``, default 8), with the batch
 sharded over it and the gradient all-reduce inserted by GSPMD.  On CPU the
-launcher forces dp*stages host devices before jax initializes.  Explicit
+launcher forces dp*stages host devices before jax initializes; on an
+accelerator the mesh takes that many of its chips.  Explicit
 ``dp=/mp=/accum=``, ``pipe=/micro=/sched=/v=/dp=``, or ``dp=/cp=`` specs
 override the search (``cp=`` = context parallelism: the model axis carries
 the sequence-sharded ppermute KV ring of ``parallel.context`` with params
@@ -29,10 +30,11 @@ points).  ``--reduced`` shrinks the arch (2 layers, small dims) for the CPU
 container.
 
 Tensor-MP and multi-DP plans likewise execute on a real local dp x mp mesh
-(forced host devices on CPU); ``--comm-runtime overlapped`` selects the
-overlap-scheduled collective runtime (``parallel.collectives``: chunked
-collective-matmul rings for the Megatron matmuls, bucketed reduce-scatter
-DP grad sync), ``gspmd`` being the monolithic-collective escape hatch.
+(chips, or forced host devices on CPU); ``--comm-runtime overlapped``
+selects the overlap-scheduled collective runtime (``parallel.collectives``:
+chunked collective-matmul rings for the Megatron matmuls, bucketed
+reduce-scatter DP grad sync), ``gspmd`` being the monolithic-collective
+escape hatch.
 
 Fault tolerance: ``--ckpt-dir``/``--ckpt-every`` write CRC-manifested
 checkpoints (``--keep-last`` retention, ``--background-save`` off the step
@@ -137,16 +139,22 @@ def parse_parallel(spec: str, devices: int, cfg, comm_runtime: str = "gspmd",
 
 
 def _ensure_host_devices(n: int):
-    """Force ``n`` host platform devices — must run before jax initializes
-    its backend (which is why main() defers every jax call until after the
-    plan is known)."""
+    """Force ``n`` CPU host devices — must run before jax initializes its
+    backend (which is why main() defers every jax call until after the plan
+    is known).  It only shapes the CPU backend: on an accelerator the mesh
+    takes its devices from the chips."""
     flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (
             flags + f" --xla_force_host_platform_device_count={n}").strip()
 
 
-def main():
+def main(argv=None) -> dict:
+    """Run the training CLI on ``argv`` (default ``sys.argv[1:]``) and return
+    the loop's summary: ``steps``, per-step losses (``history``) and host
+    seconds (``step_s``), ``final_loss``, ``wall_s``, the fault counters and
+    the mesh's device ids (``devices``) — without the train state, so its
+    device buffers are freed when this returns."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=200)
@@ -197,8 +205,9 @@ def main():
                          "'fail@5x2,kill@7,corrupt@10:bitflip,stall@3:0.4' "
                          "(see repro.train.fault)")
     ap.add_argument("--max-local-devices", type=int, default=8,
-                    help="cap on forced host devices for dp x stages "
-                         "pipeline execution on CPU")
+                    help="cap on the local devices a dp x mp or dp x "
+                         "stages mesh takes (chips, or forced host devices "
+                         "on CPU); the realized DP degree is clamped to it")
     ap.add_argument("--pipe-runtime", choices=["scheduled", "ad"],
                     default=None,
                     help="pipeline runtime escape hatch: 'scheduled' "
@@ -227,7 +236,7 @@ def main():
                          "restricts the search to context plans, with an "
                          "explicit spec reinterprets mp= as the ring size "
                          "(or use --parallel dp=2,cp=4 directly)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -344,11 +353,16 @@ def main():
     import jax
     import numpy as np
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[device] {dev.platform} {dev.device_kind} x{jax.device_count()}")
+
     from repro.data import DataPipeline, make_lm_dataset
     from repro.launch.mesh import make_host_mesh, make_mesh
     from repro.models.api import build_model
     from repro.optim import adamw, warmup_cosine
-    from repro.parallel.jaxcompat import set_mesh
     from repro.train.loop import LoopConfig, train_loop
     from repro.train.steps import (_make_pctx, eval_train_state,
                                    init_train_state, make_train_step,
@@ -384,8 +398,12 @@ def main():
         state_sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), state)
         batch_sh = {"tokens": NamedSharding(mesh, P("data", None)),
                     "labels": NamedSharding(mesh, P("data", None))}
+        # the state keeps its layout across steps: left to GSPMD, stage-
+        # stacked outputs come back sharded over the stage axis and the next
+        # call's in_shardings reject them
         train_step = jax.jit(train_step, donate_argnums=(0,),
-                             in_shardings=(state_sh, batch_sh))
+                             in_shardings=(state_sh, batch_sh),
+                             out_shardings=(state_sh, None))
     elif spmd:
         # tensor-MP / multi-DP: params via ShardingRules (Megatron
         # column/row specs on the model axis), batch over the data axis;
@@ -396,7 +414,8 @@ def main():
                  "labels": jax.ShapeDtypeStruct((args.batch, args.seq), i32)}
         state_sh, batch_sh = shardings_for(api, mesh, plan, opt, specs)
         train_step = jax.jit(train_step, donate_argnums=(0,),
-                             in_shardings=(state_sh, batch_sh))
+                             in_shardings=(state_sh, batch_sh),
+                             out_shardings=(state_sh, None))
     else:
         train_step = jax.jit(train_step, donate_argnums=(0,))
 
@@ -441,7 +460,7 @@ def main():
         else:
             print("[resume] no valid checkpoint found; starting fresh")
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if args.max_restarts > 0:
             from repro.train.fault import run_supervised
             summary = run_supervised(
@@ -459,6 +478,9 @@ def main():
     print(f"[done] steps={summary['steps']} final_loss="
           f"{summary['final_loss']:.4f} wall={summary['wall_s']:.1f}s "
           f"(floor {data.entropy:.4f}){flags}")
+    summary.pop("state", None)
+    summary["devices"] = [d.id for d in mesh.devices.flat]
+    return summary
 
 
 if __name__ == "__main__":

@@ -20,22 +20,8 @@ from repro.parallel.pipeline import pipeline_apply, stack_to_stages
 
 def stack_layer_params(layer_list):
     """Homogeneous per-layer param dicts -> one stacked (L, ...) pytree, the
-    layout ``parallel.pipeline.stack_to_stages`` partitions into stages.
-
-    Stacks via dynamic-update-slice rather than ``jnp.stack``: on jax 0.4.x
-    a ``concatenate`` feeding a ``shard_map`` operand miscompiles under the
-    SPMD partitioner when the mesh has an axis the in_specs do not mention
-    (the dp axis of a dp x stages mesh) — the assembled output gets an
-    erroneous cross-replica reduction.  DUS takes the same layout without
-    tripping that path; see test_pipeline_dp_stages_grads_equal_pure_dp.
-    """
-    def stack(*xs):
-        out = jnp.zeros((len(xs),) + xs[0].shape, xs[0].dtype)
-        for i, x in enumerate(xs):
-            out = jax.lax.dynamic_update_slice_in_dim(out, x[None], i, 0)
-        return out
-
-    return jax.tree.map(stack, *layer_list)
+    layout ``parallel.pipeline.stack_to_stages`` partitions into stages."""
+    return jax.tree.map(lambda *xs: jnp.stack(xs), *layer_list)
 
 
 def lstm_cell_init(key, d_in: int, d_h: int, d_proj: int = 0, dtype=jnp.float32):

@@ -1,51 +1,24 @@
-"""Version bridge for the handful of jax APIs that moved between releases.
+"""Mesh and shard_map construction with this repo's defaults.
 
-The repo targets current jax (``jax.shard_map`` / ``jax.set_mesh`` /
-``jax.sharding.AxisType``) but must also run on 0.4.x, where the same
-features live under ``jax.experimental.shard_map`` (with ``check_rep``
-instead of ``check_vma``), meshes have no axis types, and entering a mesh
-context is ``with mesh:``.  All mesh/shard_map construction in this repo
-goes through these three wrappers so the difference lives in exactly one
-place.
+``jax.make_mesh`` gives every axis the ``Explicit`` type unless told
+otherwise; the sharding rules here rely on GSPMD propagation, so meshes are
+built with ``Auto`` axes.  shard_map bodies use collectives whose
+replication the varying-manual-axes checker cannot infer (ppermute rings,
+psums of partial sums), so the check is off.
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map(..., check_vma=False)`` on new jax,
-    ``jax.experimental.shard_map.shard_map(..., check_rep=False)`` on old."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """``jax.shard_map`` with ``check_vma=False``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_mesh(shape, axis_names):
-    """``jax.make_mesh`` with Auto axis types where the concept exists."""
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with ``Auto`` axis types."""
     return jax.make_mesh(shape, axis_names,
                          axis_types=(AxisType.Auto,) * len(axis_names))
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict: newer jax returns the
-    dict directly, 0.4.x wraps it in a one-element list."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
-def set_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh: ``jax.set_mesh`` on
-    new jax; on old jax a ``Mesh`` is itself the context manager."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh

@@ -396,14 +396,8 @@ def _prepare_pipeline(mesh, axis: str, stage_params, x, n_micro: int,
     """Shared operand normalization for both pipeline runtimes.
 
     Resolves the schedule, validates the ``stack_to_stages`` params layout,
-    splits ``x`` into micro-batches, and pins the shard_map operands to
-    exactly the in_specs layouts.  Without the pinning, GSPMD may shard a
-    stage-params tree that was assembled *inside* the enclosing jit
-    (jnp.stack of per-layer params) over a DP mesh axis the in_specs do not
-    mention — and shard_map(check_rep=False) on jax 0.4.x then silently
-    consumes each rank's local shard as if it were the replicated whole,
-    corrupting the weights.  Returns
-    ``(sched, baxes, bspec, x_spec, stage_params, xm)``.
+    splits ``x`` into micro-batches sharded over the DP axes.  Returns
+    ``(sched, baxes, bspec, x_spec, xm)``.
     """
     from jax.sharding import NamedSharding
 
@@ -436,13 +430,9 @@ def _prepare_pipeline(mesh, axis: str, stage_params, x, n_micro: int,
         assert mb % dp == 0, (mb, baxes, dp)
     bspec = baxes if baxes else None
     x_spec = P(None, bspec, *([None] * (x.ndim - 1)))
-    stage_params = jax.tree.map(
-        lambda a: jax.lax.with_sharding_constraint(
-            a, NamedSharding(mesh, P(axis, *([None] * (a.ndim - 1))))),
-        stage_params)
     xm = x.reshape((n_micro, mb) + x.shape[1:])
     xm = jax.lax.with_sharding_constraint(xm, NamedSharding(mesh, x_spec))
-    return sched, baxes, bspec, x_spec, stage_params, xm
+    return sched, baxes, bspec, x_spec, xm
 
 
 def pipeline_apply(mesh, axis: str, stage_fn: Callable, stage_params, x,
@@ -475,7 +465,7 @@ def pipeline_apply(mesh, axis: str, stage_fn: Callable, stage_params, x,
     every schedule.
     """
     n_stages = mesh.shape[axis]
-    sched, baxes, bspec, x_spec, stage_params, xm = _prepare_pipeline(
+    sched, baxes, bspec, x_spec, xm = _prepare_pipeline(
         mesh, axis, stage_params, x, n_micro, batch_axes, schedule,
         virtual_stages)
     v = sched.v
@@ -813,7 +803,7 @@ def pipeline_value_and_grad(mesh, axis: str, stage_fn: Callable,
     from jax.sharding import NamedSharding
 
     n_stages = mesh.shape[axis]
-    sched, baxes, bspec, x_spec, stage_params, xm = _prepare_pipeline(
+    sched, baxes, bspec, x_spec, xm = _prepare_pipeline(
         mesh, axis, stage_params, x, n_micro, batch_axes, schedule,
         virtual_stages)
     v = sched.v

@@ -49,6 +49,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models.api import (ModelApi, cache_evict_slot, make_slot_cache)
 from repro.train.steps import make_continuous_steps
@@ -129,6 +130,12 @@ class ContinuousEngine:
         self._deadline: Dict[int, float] = {}    # rid -> absolute deadline
         self._base_key = jax.random.PRNGKey(seed)
         self.cache = make_slot_cache(api.cfg, n_slots, capacity)
+        if mesh is not None and mesh.size == 1:
+            # a one-device mesh pins the engine (a one-chip replica) to its
+            # device: the jitted steps follow their committed inputs there
+            dev = NamedSharding(mesh, P())
+            self.params = jax.device_put(params, dev)
+            self.cache = jax.device_put(self.cache, dev)
         (self._decode_tick, self._prefill_chunk,
          self._prefill_grid) = make_continuous_steps(
             api, n_slots=n_slots, temperature=temperature, mesh=mesh,

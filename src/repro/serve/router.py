@@ -112,6 +112,19 @@ class _Tracked:
     deadline: Optional[float] = None  # absolute; None = no deadline
 
 
+def replica_meshes(replicas: int, tp: int) -> List[jax.sharding.Mesh]:
+    """One ``("data", "model")`` = (1, tp) mesh per replica on disjoint
+    devices: replica ``r`` takes devices ``[r*tp, (r+1)*tp)``."""
+    devs = jax.devices()
+    need = replicas * tp
+    if need > len(devs):
+        raise ValueError(f"{replicas} replicas x tp {tp} need {need} "
+                         f"devices, only {len(devs)} visible")
+    return [jax.sharding.Mesh(np.asarray(devs[r * tp:(r + 1) * tp]
+                                         ).reshape(1, tp), ("data", "model"))
+            for r in range(replicas)]
+
+
 def _valid_prefix(tokens: Sequence[int], logprobs: Sequence[float]):
     """Progress up to (excluding) the first non-finite logprob: everything
     from poisoned math onward is untrusted and must be regenerated."""
@@ -192,23 +205,12 @@ class ReplicaRouter:
     @classmethod
     def from_choice(cls, api, params, choice, *, capacity: int, **kw):
         """Build the router an ``InferenceChoice`` plans: ``choice.replicas``
-        engine groups of ``choice.tp`` devices each (disjoint device
-        subsets, tensor-parallel inside the group when tp > 1) with
-        ``choice.slots`` request lanes per group."""
-        meshes = None
-        model_axis, batch_axes = None, ()
-        if choice.tp > 1:
-            devs = jax.devices()
-            need = choice.replicas * choice.tp
-            if need > len(devs):
-                raise ValueError(
-                    f"choice needs {choice.replicas} x {choice.tp} = {need} "
-                    f"devices, only {len(devs)} visible")
-            meshes = [jax.sharding.Mesh(
-                np.asarray(devs[r * choice.tp:(r + 1) * choice.tp]
-                           ).reshape(1, choice.tp), ("data", "model"))
-                for r in range(choice.replicas)]
-            model_axis, batch_axes = "model", ("data",)
+        engine groups of ``choice.tp`` devices each (``replica_meshes``:
+        disjoint device subsets, tensor-parallel inside the group when
+        tp > 1) with ``choice.slots`` request lanes per group."""
+        meshes = replica_meshes(choice.replicas, choice.tp)
+        model_axis, batch_axes = (("model", ("data",)) if choice.tp > 1
+                                  else (None, ()))
         return cls(api, params, replicas=choice.replicas,
                    n_slots=choice.slots, capacity=capacity, meshes=meshes,
                    model_axis=model_axis, batch_axes=batch_axes, **kw)

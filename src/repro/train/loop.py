@@ -65,7 +65,9 @@ def train_loop(train_step: Callable, state, pipeline: DataPipeline,
                on_checkpoint: Optional[Callable[[str, int], None]] = None
                ) -> Dict:
     """Runs from ``int(state.step)`` up to cfg.total_steps (or until
-    target_loss).  Returns a summary dict; see module docstring for the
+    target_loss).  Returns a summary dict (``history``: each step's loss;
+    ``step_s``: each step's host seconds up to its synced loss, the first
+    including compilation); see module docstring for the
     failure-handling semantics.  ``on_checkpoint(fname, step)`` fires after
     each completed checkpoint write (the fault-injection hook)."""
     try:
@@ -78,7 +80,7 @@ def train_loop(train_step: Callable, state, pipeline: DataPipeline,
         log_fn(f"[loop] resuming at step {start} "
                f"(epoch {epoch}, skipping {skip} batches)")
 
-    losses, history = [], []
+    losses, history, step_s = [], [], []
     retries = hangs = n_ckpts = 0
     last_saved = None
     converged = False
@@ -140,7 +142,9 @@ def train_loop(train_step: Callable, state, pipeline: DataPipeline,
             n_in_epoch = 0
             for batch in pipeline.epoch(epoch, skip=skip):
                 n_in_epoch += 1
+                t_step = time.time()
                 state, metrics, loss = run_step(batch)
+                step_s.append(time.time() - t_step)
                 step += 1
                 losses.append(loss)
                 history.append(loss)
@@ -181,7 +185,8 @@ def train_loop(train_step: Callable, state, pipeline: DataPipeline,
 
     return {"state": state, "steps": step, "epochs": epoch,
             "final_loss": history[-1] if history else float("nan"),
-            "history": history, "wall_s": time.time() - t0,
+            "history": history, "step_s": step_s,
+            "wall_s": time.time() - t0,
             "converged": converged, "start_step": start,
             "retries": retries, "hangs": hangs, "checkpoints": n_ckpts,
             "last_checkpoint_step": last_saved}

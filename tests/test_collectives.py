@@ -150,7 +150,7 @@ def test_collective_matmul_primitives_match_reference():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.jaxcompat import make_mesh, set_mesh, shard_map
+        from repro.parallel.jaxcompat import make_mesh, shard_map
         from repro.parallel.collectives import (all_gather_matmul,
                                                 matmul_reduce_scatter)
 
@@ -180,7 +180,7 @@ def test_collective_matmul_primitives_match_reference():
                               out_specs=P(None, "model", None))(x, w, w2)
                 return (y ** 2).sum()
 
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 l, g = jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))(
                     x, w, w2)
             assert abs(float(l) - float(lr)) < 1e-4, (chunks, float(l),
@@ -203,7 +203,7 @@ def test_overlapped_transformer_matches_gspmd_grid(arch):
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.models.transformer import ParallelCtx
@@ -237,7 +237,7 @@ def test_overlapped_transformer_matches_gspmd_grid(arch):
                         jax.eval_shape(api.init, key))
                     b_sh = rules.batch_shardings(
                         jax.eval_shape(lambda: batch))
-                    with set_mesh(mesh):
+                    with jax.set_mesh(mesh):
                         l, g = jax.jit(jax.value_and_grad(
                             lambda p, b: api.loss_fn(p, b, pctx)[0]),
                             in_shardings=(p_sh, b_sh))(params, batch)
@@ -263,7 +263,7 @@ def test_overlapped_hot_path_has_no_monolithic_collectives():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.models.transformer import ParallelCtx
@@ -294,7 +294,7 @@ def test_overlapped_hot_path_has_no_monolithic_collectives():
             from repro.models import layers as L
             L.set_analysis_unroll(True)
             try:
-                with set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     comp = jax.jit(
                         lambda p, b: api.loss_fn(p, b, pctx)[0],
                         in_shardings=(p_sh, b_sh)).lower(
@@ -325,7 +325,7 @@ def test_overlapped_biglstm_matches_gspmd():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.models.transformer import ParallelCtx
@@ -352,7 +352,7 @@ def test_overlapped_biglstm_matches_gspmd():
                 rules = ShardingRules(cfg, mesh, ParallelPlan())
                 p_sh = rules.params_shardings(jax.eval_shape(api.init, key))
                 b_sh = rules.batch_shardings(jax.eval_shape(lambda: batch))
-                with set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     l, g = jax.jit(jax.value_and_grad(
                         lambda p, b: api.loss_fn(p, b, pctx)[0]),
                         in_shardings=(p_sh, b_sh))(params, batch)
@@ -376,7 +376,7 @@ def test_bucketed_dp_train_step_bit_equal_and_split():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.parallel.plan import ParallelPlan
@@ -401,7 +401,7 @@ def test_bucketed_dp_train_step_bit_equal_and_split():
             plan = ParallelPlan(model_axis=None, comm_runtime=rt)
             step = make_train_step(api, opt, mesh=mesh, plan=plan,
                                    bucket_bytes=256 * 1024)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 j = jax.jit(step, in_shardings=(s_sh, b_sh))
                 comps[rt] = j.lower(state, batch).compile()
                 outs[rt] = j(state, batch)
@@ -432,7 +432,7 @@ def test_overlapped_train_step_tensor_mp():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.parallel.plan import ParallelPlan
@@ -459,7 +459,7 @@ def test_overlapped_train_step_tensor_mp():
             pctx = _make_pctx(mesh, plan, batch_shardable=True)
             s_sh, b_sh = shardings_for(api, mesh, plan, opt, specs)
             step = make_train_step(api, opt, mesh=mesh, plan=plan, pctx=pctx)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 outs[rt] = jax.jit(step, in_shardings=(s_sh, b_sh))(
                     state, batch)
         diff = max(jax.tree.leaves(jax.tree.map(

@@ -200,7 +200,7 @@ def test_ring_attention_matches_reference_grid():
         import functools
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
-        from repro.parallel.jaxcompat import make_mesh, set_mesh, shard_map
+        from repro.parallel.jaxcompat import make_mesh, shard_map
         from repro.models.layers import attention
         from repro.parallel.context import ring_attention
 
@@ -230,7 +230,7 @@ def test_ring_attention_matches_reference_grid():
 
                 lr, gr = jax.value_and_grad(loss_ref, argnums=(0, 1, 2))(
                     q, k, v)
-                with set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     l, g = jax.jit(jax.value_and_grad(
                         loss_ring, argnums=(0, 1, 2)))(q, k, v)
                 err_l = abs(float(l) - float(lr)) / abs(float(lr))
@@ -251,7 +251,7 @@ def test_cp_train_step_matches_single_device():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.parallel.plan import ParallelPlan
@@ -281,7 +281,7 @@ def test_cp_train_step_matches_single_device():
         s_sh, b_sh = shardings_for(api, mesh, plan, opt, specs)
         step = make_train_step(api, opt, mesh=mesh, plan=plan, pctx=pctx)
         import warnings
-        with set_mesh(mesh), warnings.catch_warnings():
+        with jax.set_mesh(mesh), warnings.catch_warnings():
             warnings.simplefilter("error")      # the ring MUST engage
             cp_state, cp_metrics = jax.jit(
                 step, in_shardings=(s_sh, b_sh))(state, batch)
@@ -307,7 +307,7 @@ def test_cp_hot_path_ring_only_hlo():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import dataclasses
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.models.transformer import ParallelCtx
@@ -335,7 +335,7 @@ def test_cp_hot_path_ring_only_hlo():
             from repro.models import layers as L
             L.set_analysis_unroll(True)
             try:
-                with set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     comp = jax.jit(jax.grad(
                         lambda p, b: api.loss_fn(p, b, pctx)[0]),
                         in_shardings=(p_sh, b_sh)).lower(
@@ -363,7 +363,7 @@ def test_cp_fallback_warns_and_matches():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
         import warnings
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.models.transformer import ParallelCtx
@@ -386,7 +386,7 @@ def test_cp_fallback_warns_and_matches():
         p_sh = rules.params_shardings(jax.eval_shape(api.init, key))
         with warnings.catch_warnings(record=True) as w:
             warnings.simplefilter("always")
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 l = float(jax.jit(lambda p, b: api.loss_fn(p, b, pctx)[0],
                                   in_shardings=(p_sh, None)).lower(
                     params, batch).compile()(params, batch))

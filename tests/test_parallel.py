@@ -111,7 +111,7 @@ def test_sharded_loss_equals_single_device():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.parallel.plan import ParallelPlan
@@ -130,7 +130,7 @@ def test_sharded_loss_equals_single_device():
         rules = ShardingRules(cfg, mesh, plan)
         p_sh = rules.params_shardings(jax.eval_shape(api.init, key))
         b_sh = rules.batch_shardings(jax.eval_shape(lambda: batch))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             f = jax.jit(lambda p, b: api.loss_fn(p, b)[0],
                         in_shardings=(p_sh, b_sh))
             sharded = f(params, batch)
@@ -146,7 +146,7 @@ def test_moe_ep_shard_map_equals_local():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.models.transformer import ParallelCtx
@@ -166,7 +166,7 @@ def test_moe_ep_shard_map_equals_local():
         rules = ShardingRules(cfg, mesh, ParallelPlan())
         p_sh = rules.params_shardings(jax.eval_shape(api.init, key))
         b_sh = rules.batch_shardings(jax.eval_shape(lambda: batch))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             f = jax.jit(lambda p, b: api.loss_fn(p, b, pctx)[0],
                         in_shardings=(p_sh, b_sh))
             ep = f(params, batch)
@@ -188,7 +188,7 @@ def test_pipeline_schedules_equal_sequential(stages):
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.parallel.pipeline import pipeline_apply, stack_to_stages
 
         stages = {stages}
@@ -207,7 +207,7 @@ def test_pipeline_schedules_equal_sequential(stages):
             return y
 
         y_ref, _ = jax.lax.scan(lambda x, lp: (layer(lp, x), None), x, params)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for sched in ("gpipe", "1f1b", "interleaved"):
                 v = 2 if sched == "interleaved" else 1
                 for n_micro in (2, 4, 8):
@@ -231,7 +231,7 @@ def test_pipeline_dp_stages_grads_equal_pure_dp():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models.api import build_model
 
@@ -253,7 +253,7 @@ def test_pipeline_dp_stages_grads_equal_pure_dp():
                                         n_micro=2, schedule="1f1b",
                                         batch_axes=("data",))[0]
 
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             ref_l, ref_g = jax.jit(jax.value_and_grad(dp_loss),
                                    in_shardings=(p_sh, b_sh))(params, batch)
             out_l, out_g = jax.jit(jax.value_and_grad(pipe_loss),
@@ -277,7 +277,7 @@ def test_pipeline_output_broadcast_bytes():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
         from repro.core.roofline import parse_collectives
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.parallel.pipeline import pipeline_apply, stack_to_stages
 
         stages, L, d, B, n_micro = 4, 8, 32, 16, 4
@@ -299,7 +299,7 @@ def test_pipeline_output_broadcast_bytes():
                 return pipeline_apply(mesh, "model", stage_fn, p, x,
                                       n_micro=n_micro,
                                       replicate_out=replicate_out).sum()
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 comp = jax.jit(f).lower(stacked, x).compile()
             return parse_collectives(comp.as_text(), default_group=stages)
 
@@ -387,7 +387,7 @@ def test_biglstm_pipeline_loss_equals_sequential():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models.api import build_model
 
@@ -399,7 +399,7 @@ def test_biglstm_pipeline_loss_equals_sequential():
                  "labels": jax.random.randint(key, (8, 16), 0, cfg.vocab_size, dtype=jnp.int32)}
         ref, _ = api.loss_fn(params, batch)
         mesh = make_mesh((1, 2), ("data", "model"))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out, _ = jax.jit(lambda p, b: api.pipeline_loss_fn(
                 p, b, mesh=mesh, axis="model", n_micro=4))(params, batch)
         err = abs(float(ref) - float(out))
@@ -442,7 +442,7 @@ def test_seq_sharded_flash_decode_matches_reference():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.models.transformer import ParallelCtx
@@ -459,7 +459,7 @@ def test_seq_sharded_flash_decode_matches_reference():
         ref_logits, ref_cache = api.decode_fn(params, cache, {"tokens": tokens[:, T-2:T-1]})
         mesh = make_mesh((2, 4), ("data", "model"))
         pctx = ParallelCtx(mesh=mesh, batch_axes=("data",), model_axis="model")
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out, new_cache = jax.jit(
                 lambda p, c, b: api.decode_fn(p, c, b, pctx))(
                     params, cache, {"tokens": tokens[:, T-2:T-1]})
@@ -483,7 +483,7 @@ def test_seq_sharded_flash_decode_windowed():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models import build_model
         from repro.models.transformer import ParallelCtx
@@ -518,7 +518,7 @@ def test_seq_sharded_flash_decode_windowed():
             return out
         cache = relayout(cache)
         errs = []
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             step = jax.jit(lambda p, c, b: api.decode_fn(p, c, b, pctx))
             for t in range(T-3, T):
                 out, cache = step(params, cache, {"tokens": tokens[:, t:t+1]})
@@ -534,7 +534,7 @@ def test_vocab_parallel_cross_entropy_matches():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.models.api import cross_entropy, vocab_parallel_cross_entropy
 
         key = jax.random.PRNGKey(0)
@@ -543,7 +543,7 @@ def test_vocab_parallel_cross_entropy_matches():
         labels = jax.random.randint(key, (B, S), -1, V, dtype=jnp.int32)
         ref = cross_entropy(logits, labels, V)
         mesh = make_mesh((2, 4), ("data", "model"))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = jax.jit(lambda lg, lb: vocab_parallel_cross_entropy(
                 lg, lb, V, mesh=mesh, model_axis="model",
                 batch_axes=("data",)))(logits, labels)
@@ -551,7 +551,7 @@ def test_vocab_parallel_cross_entropy_matches():
         assert err < 1e-5, (float(ref), float(out))
         # gradient must also match (it feeds the whole backward pass)
         g_ref = jax.grad(lambda lg: cross_entropy(lg, labels, V))(logits)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             g = jax.jit(jax.grad(lambda lg: vocab_parallel_cross_entropy(
                 lg, labels, V, mesh=mesh, model_axis="model",
                 batch_axes=("data",))))(logits)

@@ -186,6 +186,8 @@ def test_residual_store_spec_layout():
     """The scheduled runtime's activation store, viewed as a logical
     (stages, slots, mb, ...) array, is stage-local on the model axis with
     the micro-batch dim over DP — matching the in-shard_map carry."""
+    from jax.sharding import PartitionSpec as P
+
     from repro.configs import get_config
     from repro.parallel.plan import ParallelPlan
     from repro.parallel.sharding import ShardingRules
@@ -193,7 +195,7 @@ def test_residual_store_spec_layout():
                           _FakeMesh({"data": 4, "model": 4}),
                           ParallelPlan(mp_kind="pipeline", microbatches=4))
     spec = rules.residual_store_spec(4)
-    assert tuple(spec) == ("model", None, ("data",), None)
+    assert spec == P("model", None, ("data",), None)
     with pytest.raises(ValueError):
         rules.residual_store_spec(2)
 
@@ -222,7 +224,7 @@ _GRID_RUNNER = """
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp
-    from repro.parallel.jaxcompat import make_mesh, set_mesh
+    from repro.parallel.jaxcompat import make_mesh
     from repro.parallel.pipeline import (pipeline_apply,
                                          pipeline_value_and_grad,
                                          stack_to_stages)
@@ -257,7 +259,7 @@ _GRID_RUNNER = """
                     tm = tgt.reshape((K, B // K, d))
                     return jax.vmap(
                         lambda a, b: loss_fn(lpp, a, b))(ym, tm).sum()
-                with set_mesh(mesh):
+                with jax.set_mesh(mesh):
                     ref_l, ref_g = jax.jit(jax.value_and_grad(
                         ad_loss, argnums=(0, 1, 2)))(stacked, lp, x)
                     out_l, out_g = jax.jit(
@@ -300,7 +302,7 @@ def test_scheduled_model_grads_equal_ad_dp_stages():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models.api import build_model
 
@@ -322,7 +324,7 @@ def test_scheduled_model_grads_equal_ad_dp_stages():
                                             n_micro=4, schedule="1f1b",
                                             batch_axes=("data",))[0]
 
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 ref_l, ref_g = jax.jit(jax.value_and_grad(ad_loss))(params,
                                                                     batch)
                 (out_l, _), out_g = jax.jit(
@@ -348,7 +350,7 @@ def test_train_step_scheduled_vs_ad_runtime_bit_for_bit():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import dataclasses
         import jax, jax.numpy as jnp
-        from repro.parallel.jaxcompat import make_mesh, set_mesh
+        from repro.parallel.jaxcompat import make_mesh
         from repro.configs import get_config
         from repro.models.api import build_model
         from repro.optim import adamw, constant_lr
@@ -373,7 +375,7 @@ def test_train_step_scheduled_vs_ad_runtime_bit_for_bit():
             p = dataclasses.replace(plan, runtime=rt)
             step = make_train_step(api, opt, mesh=mesh, plan=p)
             state = init_train_state(api, opt, jax.random.PRNGKey(0))
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 step = jax.jit(step)
                 for _ in range(2):
                     state, metrics = step(state, batch)

@@ -229,21 +229,70 @@ def test_router_rejects_training_form_faults_and_duplicate_rids():
 
 
 def test_from_choice_executes_replicas_axis():
-    """``InferenceChoice.build_router`` finally executes the planner's
-    ``replicas`` axis (ROADMAP open item 1): the constructed router has
-    one engine group per planned replica and serves bit-identically."""
-    from repro.core.planner import InferenceChoice
-    from repro.parallel.plan import serve_plan
+    """``InferenceChoice.build_router`` executes the planner's ``replicas``
+    axis: one engine group per planned replica, each tp=1 replica's params
+    on its own device, serving bit-identically to one engine."""
+    out = _run_subprocess("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+        import jax
+        from repro.configs import get_config
+        from repro.core.planner import InferenceChoice
+        from repro.models import build_model
+        from repro.parallel.plan import serve_plan
+        from repro.serve import ContinuousEngine, Request
 
-    cfg, api, params = _setup()
-    ref = _solo_ref(api, params)
-    choice = InferenceChoice(replicas=2, tp=1, slots=2, step_latency=1e-3,
-                             tokens_per_s=1.0, mem_bytes=0.0,
-                             mesh_shape=(2, 1), plan=serve_plan(1))
-    rt = choice.build_router(api, params, capacity=32)
-    assert len(rt.replicas) == choice.replicas
-    assert all(r.engine.n_slots == choice.slots for r in rt.replicas)
-    _assert_bit_equal(rt.run(_reqs()), ref)
+        api = build_model(get_config("llama3_2_1b").reduced(), remat=False)
+        params = api.init(jax.random.PRNGKey(0))
+        reqs = lambda: [Request(rid=i, tokens=[1+i, 2+i, 3+i, 4+i],
+                                max_new_tokens=6) for i in range(4)]
+        eng = ContinuousEngine(api, params, n_slots=2, capacity=32)
+        ref = {r.rid: r for r in eng.run(reqs())}
+        choice = InferenceChoice(replicas=2, tp=1, slots=2, step_latency=1e-3,
+                                 tokens_per_s=1.0, mem_bytes=0.0,
+                                 mesh_shape=(2, 1), plan=serve_plan(1))
+        rt = choice.build_router(api, params, capacity=32)
+        assert len(rt.replicas) == choice.replicas
+        assert all(r.engine.n_slots == choice.slots for r in rt.replicas)
+        devs = [jax.tree.leaves(r.engine.params)[0].devices()
+                for r in rt.replicas]
+        assert devs[0] != devs[1] and all(len(d) == 1 for d in devs), devs
+        out = rt.run(reqs())
+        assert sorted(r.rid for r in out) == sorted(ref)
+        for r in out:
+            assert r.tokens == ref[r.rid].tokens, r.rid
+            assert r.logprobs == ref[r.rid].logprobs, r.rid
+            assert r.finished_reason in ("eos", "length")
+        print("FROM_CHOICE_OK")
+    """)
+    assert "FROM_CHOICE_OK" in out
+
+
+def test_serve_cli_replicas_on_distinct_devices():
+    """``launch.serve --continuous --replicas 4 --tp 1`` on four forced host
+    devices puts each replica's params on a different device, and its
+    greedy tokens equal the one-engine run of the same prompts."""
+    out = _run_subprocess("""
+        import os
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+        import jax
+        from repro.launch.serve import main
+
+        args = ["--arch", "smollm_360m", "--reduced", "--continuous",
+                "--slots", "2", "--batch", "6", "--prompt-len", "8",
+                "--max-new", "4", "--prefill-chunk", "4"]
+        one = main(args)
+        four = main(args + ["--replicas", "4"])
+        devs = [set(jax.tree.leaves(e.params)[0].devices())
+                for e in four["engines"]]
+        assert len(devs) == 4 and all(len(d) == 1 for d in devs), devs
+        assert len(set.union(*devs)) == 4, devs
+        assert [r.tokens for r in four["results"]] == \\
+            [r.tokens for r in one["results"]]
+        assert four["stats"]["completed"] == 6
+        print("CLI_REPLICAS_OK")
+    """)
+    assert "CLI_REPLICAS_OK" in out
 
 
 @pytest.mark.slow
