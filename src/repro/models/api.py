@@ -14,6 +14,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.parallel.jaxcompat import shard_map
 
 from repro.configs.base import InputShape, ModelConfig
@@ -23,6 +24,7 @@ from repro.models import transformer as tf_mod
 from repro.models.transformer import ParallelCtx
 
 
+@scopes.scoped(scopes.LOSS)
 def masked_nll_sum(logits, labels):
     """Summed token NLL in f32 (labels < 0 masked) — the additive per-micro
     numerator of ``cross_entropy``.  The scheduled pipeline runtime sums one
@@ -36,12 +38,14 @@ def masked_nll_sum(logits, labels):
     return ((logz - gold) * mask).sum()
 
 
+@scopes.scoped(scopes.LOSS)
 def cross_entropy(logits, labels, n_valid_vocab: int):
     """Mean token NLL in f32; labels < 0 are masked out."""
     mask = labels >= 0
     return masked_nll_sum(logits, labels) / jnp.maximum(mask.sum(), 1)
 
 
+@scopes.scoped(scopes.LOSS)
 def vocab_parallel_cross_entropy(logits, labels, n_valid_vocab: int, *,
                                  mesh, model_axis: str, batch_axes=()):
     """Cross-entropy over vocab-sharded logits WITHOUT gathering them

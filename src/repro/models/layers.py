@@ -13,6 +13,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.parallel.jaxcompat import shard_map
 
 # ---------------------------------------------------------------------------
@@ -157,6 +158,7 @@ def _chunked_attention(q, k, v, q_start, causal: bool, window: int, kv_chunk: in
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
+@scopes.scoped(scopes.ATTN_CORE)
 def attention(q, k, v, *, causal: bool = True, q_start=0, window: int = 0,
               softcap: float = 0.0, kv_chunk: int = 1024,
               dense_threshold: int = 8192, kv_mask=None, mask=None):
@@ -242,6 +244,7 @@ def mlp_init(key, d: int, d_ff: int, kind: str, dtype=jnp.float32):
             "wo": dense_init(ks[2], d_ff, d, dtype)}
 
 
+@scopes.scoped(scopes.MLP)
 def mlp_apply(params, x, kind: str):
     if kind == "swiglu":
         h = jax.nn.silu(x @ params["wg"].astype(x.dtype)) * (x @ params["wi"].astype(x.dtype))
@@ -254,6 +257,7 @@ def mlp_apply(params, x, kind: str):
     return h @ params["wo"].astype(x.dtype)
 
 
+@scopes.scoped(scopes.MLP)
 def mlp_apply_overlapped(params, x, kind: str, *, axis: str, axis_size: int,
                          chunks: int = 1):
     """Megatron column/row-parallel MLP on the overlap-scheduled collective

@@ -22,6 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.models.layers import dense_init
 
 
@@ -45,6 +46,7 @@ def moe_init(key, cfg, dtype=jnp.float32):
     return params
 
 
+@scopes.scoped(scopes.MOE_ROUTER)
 def _route(router_w, xf, n_experts: int, k: int):
     """Top-k routing.  Returns (ids (t,k), weights (t,k), aux_loss)."""
     logits = (xf.astype(jnp.float32) @ router_w)                 # (t, E)
@@ -68,40 +70,46 @@ def _expert_compute(xf, ids, w, wi, wg, wo, lo: int, cap: int):
     t, d = xf.shape
     k = ids.shape[1]
     e_loc = wi.shape[0]
-    flat_ids = ids.reshape(-1)                                    # (t*k,)
-    flat_w = w.reshape(-1)
-    local = (flat_ids >= lo) & (flat_ids < lo + e_loc)
-    local_ids = jnp.where(local, flat_ids - lo, e_loc)            # sentinel e_loc
-    # rank within expert group, computed on sorted order
-    order = jnp.argsort(local_ids)                                # stable
-    sorted_ids = local_ids[order]
-    counts = jnp.zeros((e_loc + 1,), jnp.int32).at[local_ids].add(1)
-    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(counts)[:-1]])
-    rank_sorted = jnp.arange(t * k, dtype=jnp.int32) - starts[sorted_ids]
-    rank = jnp.zeros((t * k,), jnp.int32).at[order].set(rank_sorted)
-    keep = local & (rank < cap)
-    slot = jnp.where(keep, sorted_slot := local_ids * cap + rank, e_loc * cap)
-    # scatter token rows into the capacity buffer (extra row = drop bin)
-    tok_idx = jnp.arange(t * k, dtype=jnp.int32) // k
-    buf_tok = jnp.full((e_loc * cap + 1,), t, jnp.int32).at[slot].set(
-        jnp.where(keep, tok_idx, t))
-    buf_tok = buf_tok[:-1]                                        # (e_loc*cap,)
-    xpad = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)], 0)
-    xb = xpad[buf_tok].reshape(e_loc, cap, d)
-    # expert FFN (swiglu), fixed-shape einsums
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xb, wg.astype(xf.dtype)))
-    h = h * jnp.einsum("ecd,edf->ecf", xb, wi.astype(xf.dtype))
-    y = jnp.einsum("ecf,efd->ecd", h, wo.astype(xf.dtype)).reshape(e_loc * cap, d)
-    # combine back, weighted
-    wpad = jnp.concatenate([flat_w, jnp.zeros((1,), xf.dtype)])
-    slot_of_flat = jnp.where(keep, slot, e_loc * cap)
-    ypad = jnp.concatenate([y, jnp.zeros((1, d), xf.dtype)], 0)
-    contrib = ypad[slot_of_flat] * wpad[jnp.where(keep, jnp.arange(t * k), t * k)][:, None]
-    out = jnp.zeros((t, d), xf.dtype).at[tok_idx].add(
-        jnp.where(keep[:, None], contrib, 0))
-    return out
+    with scopes.scope(scopes.MOE_DISPATCH):
+        flat_ids = ids.reshape(-1)                                # (t*k,)
+        flat_w = w.reshape(-1)
+        local = (flat_ids >= lo) & (flat_ids < lo + e_loc)
+        local_ids = jnp.where(local, flat_ids - lo, e_loc)        # sentinel e_loc
+        # rank within expert group, computed on sorted order
+        order = jnp.argsort(local_ids)                            # stable
+        sorted_ids = local_ids[order]
+        counts = jnp.zeros((e_loc + 1,), jnp.int32).at[local_ids].add(1)
+        starts = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                  jnp.cumsum(counts)[:-1]])
+        rank_sorted = jnp.arange(t * k, dtype=jnp.int32) - starts[sorted_ids]
+        rank = jnp.zeros((t * k,), jnp.int32).at[order].set(rank_sorted)
+        keep = local & (rank < cap)
+        slot = jnp.where(keep, local_ids * cap + rank, e_loc * cap)
+        # scatter token rows into the capacity buffer (extra row = drop bin)
+        tok_idx = jnp.arange(t * k, dtype=jnp.int32) // k
+        buf_tok = jnp.full((e_loc * cap + 1,), t, jnp.int32).at[slot].set(
+            jnp.where(keep, tok_idx, t))
+        buf_tok = buf_tok[:-1]                                    # (e_loc*cap,)
+        xpad = jnp.concatenate([xf, jnp.zeros((1, d), xf.dtype)], 0)
+        xb = xpad[buf_tok].reshape(e_loc, cap, d)
+    with scopes.scope(scopes.MOE_EXPERTS):
+        # expert FFN (swiglu), fixed-shape einsums
+        h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xb, wg.astype(xf.dtype)))
+        h = h * jnp.einsum("ecd,edf->ecf", xb, wi.astype(xf.dtype))
+        y = jnp.einsum("ecf,efd->ecd", h,
+                       wo.astype(xf.dtype)).reshape(e_loc * cap, d)
+    with scopes.scope(scopes.MOE_COMBINE):
+        # combine back, weighted
+        wpad = jnp.concatenate([flat_w, jnp.zeros((1,), xf.dtype)])
+        slot_of_flat = jnp.where(keep, slot, e_loc * cap)
+        ypad = jnp.concatenate([y, jnp.zeros((1, d), xf.dtype)], 0)
+        contrib = (ypad[slot_of_flat]
+                   * wpad[jnp.where(keep, jnp.arange(t * k), t * k)][:, None])
+        return jnp.zeros((t, d), xf.dtype).at[tok_idx].add(
+            jnp.where(keep[:, None], contrib, 0))
 
 
+@scopes.scoped(scopes.MOE_EXPERTS)
 def _shared_expert(params, x):
     h = jax.nn.silu(x @ params["wg"].astype(x.dtype)) * (x @ params["wi"].astype(x.dtype))
     return h @ params["wo"].astype(x.dtype)
@@ -143,7 +151,8 @@ def moe_ffn(params, x, cfg, *, model_axis: Optional[str] = None,
         axes = (model_axis,) + tuple(ff_axes or ())
         # reduce in the activation dtype: XLA upcasts the combine scatter-add
         # to f32, and psum-ing that doubles EP wire bytes (§Perf iteration C.1)
-        out = jax.lax.psum(out.astype(x.dtype), axes)
+        with scopes.scope(scopes.MOE_COMBINE):
+            out = jax.lax.psum(out.astype(x.dtype), axes)
         aux = jax.lax.pmean(aux, model_axis)
     return out.reshape(b, s, d), aux
 

@@ -23,6 +23,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro import scopes
 from repro.parallel.jaxcompat import shard_map
 from jax.sharding import PartitionSpec as P
 
@@ -222,6 +223,7 @@ def _attn_batch_respec(pctx, cfg, b: int, t: int = 0):
     return None, None, None
 
 
+@scopes.scoped(scopes.ATTN_PROJ)
 def _self_attention(p, x, cfg, *, window: int, pos0, cache_kv=None,
                     cache_len=None, pctx=None):
     """Self-attention over x (+ optional cache for decode).
@@ -367,7 +369,8 @@ def block_apply(cfg, p, x, *, mode: str, window: int, pos0, cache=None,
                 new_cache = {"wkv_S": S, "tm_x": tm_x, "cm_x": cm_x}
         return x, new_cache, aux
 
-    h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
+    with scopes.scope(scopes.ATTN_PROJ):
+        h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     if mode == "decode":
         cache_kv = {"k": cache["k"], "v": cache["v"]}
         attn_out, (k_new, v_new) = _self_attention(
@@ -439,7 +442,8 @@ def block_apply(cfg, p, x, *, mode: str, window: int, pos0, cache=None,
         if mode in ("decode", "prefill"):
             new_cache.update({"ssm_h": ssm_state_new["h"],
                               "ssm_conv": ssm_state_new["conv"]})
-    x = x + attn_out
+    with scopes.scope(scopes.ATTN_PROJ):
+        x = x + attn_out
 
     if cfg.encoder_layers:
         hx = L.rms_norm(x, p["lnx"], cfg.norm_eps)
@@ -452,7 +456,9 @@ def block_apply(cfg, p, x, *, mode: str, window: int, pos0, cache=None,
                 new_cache.update({"xk": enc_kv[0], "xv": enc_kv[1]})
         x = x + _cross_attention(p["xattn"], hx, enc_kv, cfg)
 
-    h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+    # an MoE block's norm feeds the router, its residual add ends the combine
+    with scopes.scope(scopes.MOE_ROUTER if cfg.is_moe else scopes.MLP):
+        h2 = L.rms_norm(x, p["ln2"], cfg.norm_eps)
     if cfg.is_moe:
         # decode batches are tiny: use the no-drop capacity so cached decoding
         # is numerically identical to teacher-forced forward
@@ -483,7 +489,8 @@ def block_apply(cfg, p, x, *, mode: str, window: int, pos0, cache=None,
         aux = aux + cfg.router_aux_loss * moe_aux
     else:
         mlp_out = L.mlp_apply(p["mlp"], h2, cfg.mlp_kind)
-    x = x + mlp_out
+    with scopes.scope(scopes.MOE_COMBINE if cfg.is_moe else scopes.MLP):
+        x = x + mlp_out
     return x, new_cache, aux
 
 
@@ -524,6 +531,7 @@ def overlapped_supported(cfg, pctx: Optional[ParallelCtx],
             and t // msz % max(pctx.comm_chunks, 1) == 0)
 
 
+@scopes.scoped(scopes.ATTN_PROJ)
 def _self_attention_overlapped(p, x, cfg, *, window: int, axis: str, msz: int,
                                chunks: int):
     """Self-attention with q/k/v/o on the collective-matmul rings, for use
@@ -582,14 +590,16 @@ def overlapped_block_apply(cfg, p, x, *, window: int,
     kv_sharded = cfg.n_kv_heads % msz == 0
 
     def local(lp, xl):
-        h = L.rms_norm(xl, lp["ln1"], cfg.norm_eps)
-        xl = xl + _self_attention_overlapped(lp["attn"], h, cfg,
-                                             window=window, axis=axis,
-                                             msz=msz, chunks=chunks)
-        h2 = L.rms_norm(xl, lp["ln2"], cfg.norm_eps)
-        return xl + L.mlp_apply_overlapped(lp["mlp"], h2, cfg.mlp_kind,
-                                           axis=axis, axis_size=msz,
-                                           chunks=chunks)
+        with scopes.scope(scopes.ATTN_PROJ):
+            h = L.rms_norm(xl, lp["ln1"], cfg.norm_eps)
+            xl = xl + _self_attention_overlapped(lp["attn"], h, cfg,
+                                                 window=window, axis=axis,
+                                                 msz=msz, chunks=chunks)
+        with scopes.scope(scopes.MLP):
+            h2 = L.rms_norm(xl, lp["ln2"], cfg.norm_eps)
+            return xl + L.mlp_apply_overlapped(lp["mlp"], h2, cfg.mlp_kind,
+                                               axis=axis, axis_size=msz,
+                                               chunks=chunks)
 
     col, row = P(None, axis), P(axis, None)
     kv = col if kv_sharded else P(None, None)
@@ -642,21 +652,23 @@ def cp_block_apply(cfg, p, x, *, window: int, pctx: ParallelCtx):
 
     def local(lp, xl):
         b = xl.shape[0]
-        h = L.rms_norm(xl, lp["ln1"], cfg.norm_eps)
-        q = (h @ lp["attn"]["wq"].astype(h.dtype)).reshape(b, t_loc, nh, hd)
-        k = (h @ lp["attn"]["wk"].astype(h.dtype)).reshape(b, t_loc, nkv, hd)
-        v = (h @ lp["attn"]["wv"].astype(h.dtype)).reshape(b, t_loc, nkv, hd)
-        j = jax.lax.axis_index(axis)
-        positions = jnp.broadcast_to(j * t_loc + jnp.arange(t_loc),
-                                     (b, t_loc))
-        q = L.apply_rope(q, positions, cfg.rope_theta)
-        k = L.apply_rope(k, positions, cfg.rope_theta)
-        out = ring_attention(q, k, v, axis=axis, axis_size=csz,
-                             causal=True, window=window)
-        xl = xl + (out.reshape(b, t_loc, nh * hd)
-                   @ lp["attn"]["wo"].astype(xl.dtype))
-        h2 = L.rms_norm(xl, lp["ln2"], cfg.norm_eps)
-        return xl + L.mlp_apply(lp["mlp"], h2, cfg.mlp_kind)
+        with scopes.scope(scopes.ATTN_PROJ):
+            h = L.rms_norm(xl, lp["ln1"], cfg.norm_eps)
+            q = (h @ lp["attn"]["wq"].astype(h.dtype)).reshape(b, t_loc, nh, hd)
+            k = (h @ lp["attn"]["wk"].astype(h.dtype)).reshape(b, t_loc, nkv, hd)
+            v = (h @ lp["attn"]["wv"].astype(h.dtype)).reshape(b, t_loc, nkv, hd)
+            j = jax.lax.axis_index(axis)
+            positions = jnp.broadcast_to(j * t_loc + jnp.arange(t_loc),
+                                         (b, t_loc))
+            q = L.apply_rope(q, positions, cfg.rope_theta)
+            k = L.apply_rope(k, positions, cfg.rope_theta)
+            out = ring_attention(q, k, v, axis=axis, axis_size=csz,
+                                 causal=True, window=window)
+            xl = xl + (out.reshape(b, t_loc, nh * hd)
+                       @ lp["attn"]["wo"].astype(xl.dtype))
+        with scopes.scope(scopes.MLP):
+            h2 = L.rms_norm(xl, lp["ln2"], cfg.norm_eps)
+            return xl + L.mlp_apply(lp["mlp"], h2, cfg.mlp_kind)
 
     rp, rw = P(None), P(None, None)
     p_specs = {"ln1": rp, "ln2": rp,
@@ -699,11 +711,13 @@ def encode(cfg, params, frames):
 # top-level entry points
 # ---------------------------------------------------------------------------
 
+@scopes.scoped(scopes.EMBED)
 def _embed(cfg, params, tokens):
     x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.dtype(cfg.dtype))
     return x * (cfg.d_model ** 0.5 if cfg.tie_embeddings else 1.0)
 
 
+@scopes.scoped(scopes.HEAD)
 def _head(cfg, params, x):
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

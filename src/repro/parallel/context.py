@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import scopes
 from repro.models.layers import NEG_INF, repeat_kv
 from repro.parallel.collectives import _ring_perm
 
@@ -211,6 +212,7 @@ def _ring_attn_bwd(axis, axis_size, causal, window, res, dout):
 _ring_attn.defvjp(_ring_attn_fwd, _ring_attn_bwd)
 
 
+@scopes.scoped(scopes.ATTN_CORE)
 def ring_attention(q, k, v, *, axis: str, axis_size: int,
                    causal: bool = True, window: int = 0):
     """Context-parallel GQA attention over a sequence-sharded ring.

@@ -116,6 +116,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import scopes
 from repro.parallel.jaxcompat import shard_map
 
 SCHEDULE_KINDS = ("gpipe", "1f1b", "interleaved")
@@ -518,7 +519,8 @@ def pipeline_apply(mesh, axis: str, stage_fn: Callable, stage_params, x,
             y = stage_fn(chunk_params, inp)
             write = jnp.take(erow, stage) == 1
             outs = outs.at[m_idx].set(jnp.where(write, y, outs[m_idx]))
-            state = jax.lax.ppermute(y, axis, ring)
+            with scopes.scope(scopes.PIPE_EXCHANGE):
+                state = jax.lax.ppermute(y, axis, ring)
             if not replicate_out:
                 # write the arriving broadcast value (its share of the
                 # output cotangent rides the transposed ring back to the
@@ -529,8 +531,9 @@ def pipeline_apply(mesh, axis: str, stage_fn: Callable, stage_params, x,
                 outs = outs.at[jnp.clip(bm, 0, n_micro - 1)].set(
                     jnp.where(bm >= 0,
                               bv, outs[jnp.clip(bm, 0, n_micro - 1)]))
-                bcast = jax.lax.ppermute(
-                    jnp.where(write, y, bcast), axis, ring)
+                with scopes.scope(scopes.PIPE_EXCHANGE):
+                    bcast = jax.lax.ppermute(
+                        jnp.where(write, y, bcast), axis, ring)
             return (state, bcast, outs), None
 
         (_, _, outs), _ = jax.lax.scan(
@@ -902,8 +905,9 @@ def pipeline_value_and_grad(mesh, axis: str, stage_fn: Callable,
                                (run_idle, run_fwd, run_bwd), ops)
             # 3. ring sends — collectives stay outside the switch so every
             # stage participates every tick
-            fmsg = jax.lax.ppermute(fy, axis, ring_fwd)
-            bmsg = jax.lax.ppermute(bg, axis, ring_bwd)
+            with scopes.scope(scopes.PIPE_EXCHANGE):
+                fmsg = jax.lax.ppermute(fy, axis, ring_fwd)
+                bmsg = jax.lax.ppermute(bg, axis, ring_bwd)
             return (fmsg, bmsg, fstore, bstore, gacc, dxm, loss_acc,
                     lpg), None
 
@@ -922,11 +926,13 @@ def pipeline_value_and_grad(mesh, axis: str, stage_fn: Callable,
         # zeros); stage grads are per-stage partials over the DP shards;
         # dxm is stage 0's input cotangent, per DP shard
         reduce_axes = (axis,) + baxes
-        loss = jax.lax.psum(loss_acc, reduce_axes)
-        lpg = jax.tree.map(lambda a: jax.lax.psum(a, reduce_axes), lpg)
-        if baxes:
-            gacc = jax.tree.map(lambda a: jax.lax.psum(a, baxes), gacc)
-        dxm = jax.lax.psum(dxm, axis)
+        with scopes.scope(scopes.GRAD_SYNC):
+            loss = jax.lax.psum(loss_acc, reduce_axes)
+            lpg = jax.tree.map(lambda a: jax.lax.psum(a, reduce_axes), lpg)
+            if baxes:
+                gacc = jax.tree.map(lambda a: jax.lax.psum(a, baxes), gacc)
+        with scopes.scope(scopes.PIPE_EXCHANGE):
+            dxm = jax.lax.psum(dxm, axis)
         return loss, jax.tree.map(lambda a: a[None], gacc), lpg, dxm
 
     p_specs = jax.tree.map(lambda _: P(axis), stage_params)

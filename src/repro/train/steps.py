@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import scopes
 from repro.models.api import ModelApi
 from repro.models.transformer import ParallelCtx
 from repro.optim.optimizers import Optimizer, apply_updates, clip_by_global_norm
@@ -192,12 +193,13 @@ def make_train_step(api: ModelApi, optimizer: Optimizer, *, mesh=None,
 
             def local(p, bt):
                 loss, metrics, grads = gspmd_total_grads(p, bt)
-                grads = bucketed_grad_sync(grads, dp_axis=dp_axis,
-                                           dp_size=mesh.shape[dp_axis],
-                                           pod_axis=pod_axis,
-                                           bucket_bytes=bkt)
-                grads = jax.tree.map(
-                    lambda g: (g / dp_degree).astype(g.dtype), grads)
+                with scopes.scope(scopes.GRAD_SYNC):
+                    grads = bucketed_grad_sync(grads, dp_axis=dp_axis,
+                                               dp_size=mesh.shape[dp_axis],
+                                               pod_axis=pod_axis,
+                                               bucket_bytes=bkt)
+                    grads = jax.tree.map(
+                        lambda g: (g / dp_degree).astype(g.dtype), grads)
                 loss = jax.lax.pmean(loss, dp_axes_live)
                 metrics = {k: jax.lax.pmean(v, dp_axes_live)
                            for k, v in metrics.items()}
@@ -211,12 +213,13 @@ def make_train_step(api: ModelApi, optimizer: Optimizer, *, mesh=None,
     def train_step(state: TrainState, batch):
         params = state.params
         loss, metrics, grads = total_grads(params, batch)
-        if clip_norm:
-            grads, gnorm = clip_by_global_norm(grads, clip_norm)
-            metrics = dict(metrics, grad_norm=gnorm)
-        updates, opt_state = optimizer.update(grads, state.opt_state, params,
-                                              state.step)
-        params = apply_updates(params, updates)
+        with scopes.scope(scopes.OPTIM):
+            if clip_norm:
+                grads, gnorm = clip_by_global_norm(grads, clip_norm)
+                metrics = dict(metrics, grad_norm=gnorm)
+            updates, opt_state = optimizer.update(grads, state.opt_state,
+                                                  params, state.step)
+            params = apply_updates(params, updates)
         new_state = TrainState(params=params, opt_state=opt_state,
                                step=state.step + 1)
         return new_state, metrics
