@@ -1,11 +1,20 @@
-"""Flash attention Pallas TPU kernel (forward).
+"""Flash attention on the TPU, as Pallas kernels.
 
-Tiling: grid (batch*heads, n_q_blocks, n_kv_blocks); the kv axis is the
-innermost (sequential) dimension so the online-softmax state lives in VMEM
-scratch across kv iterations.  Block shapes are MXU-aligned (q/kv block x
-head_dim, multiples of 128 where the head_dim allows).  Causal and
-sliding-window masking happen on block indices first (whole-block skip) and
-lane indices second.
+``causal_self_attention`` is the one on the main path: the train step's
+causal self-attention (``models.layers.attention``) runs through it on a
+TPU.  It calls JAX's bundled splash-attention kernel, which has a forward
+and a backward pass, skips the blocks its mask hides, and reads grouped
+K/V heads without repeating them.
+
+``flash_attention`` below it is a forward-only kernel that no model path
+calls.
+
+Tiling of ``flash_attention``: grid (batch*heads, n_q_blocks, n_kv_blocks);
+the kv axis is the innermost (sequential) dimension so the online-softmax
+state lives in VMEM scratch across kv iterations.  Block shapes are
+MXU-aligned (q/kv block x head_dim, multiples of 128 where the head_dim
+allows).  Causal and sliding-window masking happen on block indices first
+(whole-block skip) and lane indices second.
 
 VMEM budget per step: q (bq, hd) + k,v (bk, hd) + scores (bq, bk) f32 +
 acc (bq, hd) f32 + m,l (bq,) — e.g. bq=bk=512, hd=128: ~2.4 MB, well under
@@ -24,6 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 NEG_INF = -1e30
 
@@ -131,3 +141,57 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     )(qr, kr, vr)
     out = out[:, :tq].reshape(b, h, tq, hd).transpose(0, 2, 1, 3)
     return out
+
+
+# Blocks of the splash kernel, forward and backward: q and kv blocks of up to
+# SPLASH_BLOCK rows, each kv block computed SPLASH_BLOCK_COMPUTE columns at a
+# time; a sequence takes the largest such power of two that divides it.  One
+# fused backward kernel computes dq with dk and dv; q and k are laid out
+# sequence-minor.  Chosen by timing the kernel alone, forward and backward,
+# on a TPU v5e at (8, 2048, 15 / 5 and 16 / 8 heads, 64).
+SPLASH_BLOCK = 1024
+SPLASH_BLOCK_COMPUTE = 512
+
+
+def _splash_block_sizes(t: int) -> splash.BlockSizes:
+    blk = math.gcd(t, SPLASH_BLOCK)
+    compute = math.gcd(t, SPLASH_BLOCK_COMPUTE)
+    seq_minor = splash.QKVLayout.SEQ_MINOR
+    return splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=compute,
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=compute,
+        use_fused_bwd_kernel=True, q_layout=seq_minor, k_layout=seq_minor)
+
+
+def _splash_mask(t: int, hq: int, window: int) -> splash.MultiHeadMask:
+    if window > 0:
+        # key j visible from query i iff i - window < j <= i
+        mask = splash.LocalMask((t, t), (window - 1, 0), 0)
+    else:
+        mask = splash.CausalMask((t, t))
+    return splash.MultiHeadMask([mask] * hq)
+
+
+def causal_self_attention(q, k, v, *, window: int = 0, softcap: float = 0.0,
+                          interpret: bool = False):
+    """Causal self-attention as a flash kernel with a forward and a backward.
+
+    q: (B, T, Hq, hd); k, v: (B, T, Hkv, hd) with Hq a multiple of Hkv (GQA,
+    not repeated).  ``window`` > 0 restricts key j to (i - window, i];
+    ``softcap`` > 0 caps the scaled logits at softcap * tanh(s / softcap).
+    T must be a multiple of 128.  Matmul operands keep the input dtype and
+    accumulate in float32.  Returns (B, T, Hq, hd) in q's dtype.
+    """
+    b, t, hq, hd = q.shape
+    # splash folds the mask into block tables on the host once per mask and
+    # block shape (it caches them), and reads the K/V heads from k and v;
+    # the tables become constants of the trace that builds the kernel
+    kernel = splash.make_splash_mha(
+        _splash_mask(t, hq, window), block_sizes=_splash_block_sizes(t),
+        head_shards=1, q_seq_shards=1,
+        attn_logits_soft_cap=softcap or None, interpret=interpret)
+    # splash does not scale the logits
+    q = (q * (1.0 / math.sqrt(hd))).astype(q.dtype)
+    to_heads = lambda x: x.transpose(0, 2, 1, 3)      # (B, H, T, hd)
+    out = jax.vmap(kernel)(to_heads(q), to_heads(k), to_heads(v))
+    return to_heads(out)

@@ -1,5 +1,6 @@
 """Shared neural-net layers: norms, RoPE, GQA attention (full / sliding-window /
-chunked-online-softmax), KV caches, and MLP variants.
+chunked-online-softmax, or the flash kernel on a TPU), KV caches, and MLP
+variants.
 
 Everything is a pure function over explicit param pytrees so that the parallel
 runtime can assign `NamedSharding`s by param path and `jax.eval_shape` can
@@ -7,6 +8,9 @@ derive ShapeDtypeStructs for the multi-pod dry-run without allocating.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
+import functools
 import math
 from typing import Optional
 
@@ -14,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import scopes
+from repro.kernels import flash_attention as flash
 from repro.parallel.jaxcompat import shard_map
 
 # ---------------------------------------------------------------------------
@@ -158,26 +163,61 @@ def _chunked_attention(q, k, v, q_start, causal: bool, window: int, kv_chunk: in
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-@scopes.scoped(scopes.ATTN_CORE)
-def attention(q, k, v, *, causal: bool = True, q_start=0, window: int = 0,
-              softcap: float = 0.0, kv_chunk: int = 1024,
-              dense_threshold: int = 8192, kv_mask=None, mask=None):
-    """GQA attention.  q: (B,Tq,Hq,hd); k,v: (B,Tk,Hkv,hd).
+# the counters of the open ``count_attention_paths`` blocks
+_path_counts: list = []
 
-    ``window`` > 0 restricts key j to (i - window, i].  ``kv_mask`` is an
-    optional (B, Tk) bool of valid cache slots (decode).  ``mask`` is an
-    explicit (B, Tq, Tk) bool overriding all derived masking (per-request
-    positions in the slotted serving cache); it forces the dense path.
-    Otherwise chooses a dense path for short KV and the chunked
-    online-softmax path (flash algorithm) for long KV.
-    """
+
+@contextlib.contextmanager
+def count_attention_paths():
+    """Counts, while open, the ``attention`` calls traced on each path:
+    ``kernel`` (the flash kernel), ``masked`` (an explicit mask), ``dense``
+    or ``chunked``.  Counting happens at trace time: a jitted function
+    counts once per trace, a layer scan once for its body."""
+    counts = collections.Counter()
+    _path_counts.append(counts)
+    try:
+        yield counts
+    finally:
+        _path_counts.remove(counts)
+
+
+def _operands_on_one_device() -> bool:
+    """Whether this trace's arrays are whole on one device: no mesh on a
+    one-device process, a one-device mesh, or a shard_map body (every mesh
+    axis manual).  Under a GSPMD mesh of several devices they are not."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return jax.device_count() == 1
+    return mesh.size == 1 or set(mesh.manual_axes) == set(mesh.axis_names)
+
+
+def _takes_kernel(q, k, *, causal, q_start, kv_mask, mask) -> bool:
+    """Causal self-attention over the whole sequence, with no explicit mask,
+    a sequence the kernel's blocks tile, on operands one device holds."""
+    tq, tk = q.shape[1], k.shape[1]
+    return (causal and mask is None and kv_mask is None
+            and isinstance(q_start, int) and q_start == 0
+            and tq == tk and tq % 128 == 0 and _operands_on_one_device())
+
+
+def _xla_path(tk: int, *, softcap, dense_threshold, mask) -> str:
+    if mask is not None:
+        return "masked"
+    if tk <= dense_threshold or softcap:
+        return "dense"
+    return "chunked"
+
+
+def _xla_attention(q, k, v, *, path, causal, q_start, window, softcap,
+                   kv_chunk, kv_mask, mask):
+    """Attention as XLA ops on ``path`` (``_xla_path``)."""
     hq, hkv = q.shape[2], k.shape[2]
     k = repeat_kv(k, hq // hkv)
     v = repeat_kv(v, hq // hkv)
-    if mask is not None:
-        return _dense_attention(q, k, v, mask[:, None], softcap)
     tq, tk = q.shape[1], k.shape[1]
-    if tk <= dense_threshold or softcap:
+    if path == "masked":
+        return _dense_attention(q, k, v, mask[:, None], softcap)
+    if path == "dense":
         qpos = q_start + jnp.arange(tq)
         kpos = jnp.arange(tk)
         mask = jnp.ones((tq, tk), bool)
@@ -191,6 +231,41 @@ def attention(q, k, v, *, causal: bool = True, q_start=0, window: int = 0,
         return _dense_attention(q, k, v, mask, softcap)
     assert kv_mask is None, "chunked path expects a fully-valid cache"
     return _chunked_attention(q, k, v, q_start, causal, window, kv_chunk)
+
+
+@scopes.scoped(scopes.ATTN_CORE)
+def attention(q, k, v, *, causal: bool = True, q_start=0, window: int = 0,
+              softcap: float = 0.0, kv_chunk: int = 1024,
+              dense_threshold: int = 8192, kv_mask=None, mask=None):
+    """GQA attention.  q: (B,Tq,Hq,hd); k,v: (B,Tk,Hkv,hd).
+
+    ``window`` > 0 restricts key j to (i - window, i].  ``kv_mask`` is an
+    optional (B, Tk) bool of valid cache slots (decode).  ``mask`` is an
+    explicit (B, Tq, Tk) bool overriding all derived masking (per-request
+    positions in the slotted serving cache); it forces the dense path.
+
+    Causal self-attention from position 0 with no explicit mask, T a
+    multiple of 128 and operands on one device runs as the splash flash
+    kernel (forward and backward) when lowered for a TPU.  Otherwise, and
+    on other platforms, it takes a dense path for short KV and the chunked
+    online-softmax path (flash algorithm) for long KV.
+    """
+    xla_path = _xla_path(k.shape[1], softcap=softcap,
+                         dense_threshold=dense_threshold, mask=mask)
+    kernel = _takes_kernel(q, k, causal=causal, q_start=q_start,
+                           kv_mask=kv_mask, mask=mask)
+    for counts in _path_counts:
+        counts["kernel" if kernel else xla_path] += 1
+    xla = functools.partial(
+        _xla_attention, path=xla_path, causal=causal, q_start=q_start,
+        window=window, softcap=softcap, kv_chunk=kv_chunk, kv_mask=kv_mask,
+        mask=mask)
+    if not kernel:
+        return xla(q, k, v)
+    return jax.lax.platform_dependent(
+        q, k, v, default=xla,
+        tpu=functools.partial(flash.causal_self_attention, window=window,
+                              softcap=softcap))
 
 
 # ---------------------------------------------------------------------------

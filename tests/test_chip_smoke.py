@@ -29,6 +29,8 @@ def test_one_chip_phases_reduced():
     """)
     assert r.returncode == 0 and "PHASES_OK" in r.stdout, r.stderr[-3000:]
     assert "[smoke] train dp=1,mp=1" in r.stdout
+    # seq 32 is no multiple of the kernel's 128: the dense path
+    assert "attention calls traced by path {'dense': " in r.stdout
     assert "match the full forward" in r.stdout
 
 
@@ -61,6 +63,25 @@ def test_check_raises_on_failure(monkeypatch):
     chip_smoke._check(True, "kept")
     with pytest.raises(RuntimeError, match="first loss out of band"):
         chip_smoke._check(False, "first loss out of band")
+
+
+@pytest.mark.parametrize("logits,token,ok", [
+    ([4.0, 3.5, 1.0], 0, True),             # the argmax
+    ([4.0, 4.0, 1.0], 1, True),             # an exact tie at the top
+    ([4.03125, 4.0, 1.0], 1, True),         # runner-up one unit below
+    ([4.03125, 4.03125, 4.0], 2, True),     # runner-up below a tied top
+    ([3.84375, 3.8125, 1.0], 1, False),     # two units below
+    ([4.03125, 4.0, 4.0], 2, True),         # tied runner-ups
+    ([4.0625, 4.03125, 4.0], 2, False),     # third
+], ids=["argmax", "tie", "runner_up", "below_tied_top", "two_units",
+        "tied_runner_up", "third"])
+def test_greedy_pick(monkeypatch, logits, token, ok):
+    """The serve check takes the reference's argmax, or its runner-up only
+    where the top two lie one bf16 unit apart."""
+    import numpy as np
+    monkeypatch.syspath_prepend(ROOT)
+    import chip_smoke
+    assert chip_smoke._greedy_pick(np.array(logits, np.float32), token) == ok
 
 
 def test_compile_cache_placement(monkeypatch):
