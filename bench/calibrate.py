@@ -6,12 +6,15 @@
 For every seed the program's train step (built once, as a run builds it) is
 driven through the mix's checked steps and compared with the float32
 reference, as a run compares it.  For the seeds under ``--control`` the
-reference is also put in the program's place twice and compared with the
+reference is also put in the program's place and compared with the
 float32 reference in the same way: computed with float8 matmul operands
-(the control), and over the first half of each batch's rows alone (the
-fault of a step that leaves half of the batch out).  A step that returns
-its state unchanged reads a change gap of 1 and needs no run.  Prints one
-JSON line per seed and a summary; ``--out`` also writes them to a file."""
+(the control), over the first half of each batch's rows alone (the fault
+of a step that leaves half of the batch out), and, where the program runs
+its layers as pipeline stages, with the second half of the layers fed zeros
+in place of the first half's output (the fault of the exchange between the
+stages left out).  A step that returns its state unchanged reads a change
+gap of 1 and needs no run.  Prints one JSON line per seed and a summary;
+``--out`` also writes them to a file."""
 from __future__ import annotations
 
 import time
@@ -27,7 +30,7 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import checks, common, weights  # noqa: E402
+from bench import checks, common  # noqa: E402
 
 NUMBERS = ("loss_gap", "grad_gap", "change_gap")
 
@@ -51,12 +54,12 @@ def main(argv=None, *, root=common.ROOT, compile_cache: bool = True) -> dict:
     driver = bench.driver(mix["driver"])
     ref = bench.reference(mc["reference"])
     prog = driver.build(mc, mix, ref)
-    make_params = weights.make_params_fn(ref.param_shapes(mc))
     n = mix["checked_steps"]
-    reference, control = (
-        checks.Reference(ref, mc, mix["optimizer"], ref.Numerics(mode),
-                         make_params, mix.get("reference_block_rows"))
-        for mode in ("float32", "float8"))
+    reference, control, cut = (
+        checks.Reference(ref, mc, mix["optimizer"], num, prog.make_params,
+                         mix.get("reference_block_rows"))
+        for num in (ref.Numerics("float32"), ref.Numerics("float8"),
+                    ref.Numerics("float32", cut_exchange=True)))
     rows = []
     for seed in sorted(set(seeds) | set(controls)):
         t0 = time.perf_counter()
@@ -82,13 +85,16 @@ def main(argv=None, *, root=common.ROOT, compile_cache: bool = True) -> dict:
                 key, [(t[: len(t) // 2], lb[: len(lb) // 2])
                       for t, lb in pairs])
             row["half_batch"] = checks.compare(hr, rr)
+            if prog.pipeline:
+                row["no_exchange"] = checks.compare(cut.readings(key, pairs),
+                                                    rr)
         row["seconds"] = time.perf_counter() - t0
         rows.append(row)
         print(json.dumps(row), flush=True)
     summary = {"cell": cell["name"], "device": jax.devices()[0].device_kind,
                "seconds": time.perf_counter() - T_PROCESS}
     for kind, pick in (("program", max), ("control", min),
-                       ("half_batch", min)):
+                       ("half_batch", min), ("no_exchange", min)):
         got = [r[kind] for r in rows if kind in r]
         if got:
             summary[kind] = {k: pick(g[k] for g in got) for k in NUMBERS}

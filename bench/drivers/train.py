@@ -19,13 +19,12 @@ import shutil
 import tempfile
 import time
 
-from bench import checks, flops, trace, weights
+from bench import checks, trace, weights
 from bench.common import seed_key
-from bench.model import program_config
 
 WINDOW, STEP, FEED, FETCH = ("bench.window", "bench.step", "bench.feed",
                              "bench.fetch")
-FAULTS = ("unchanged", "half_batch")
+FAULTS = ("unchanged", "half_batch", "no_exchange")
 
 
 @dataclasses.dataclass
@@ -35,8 +34,10 @@ class Program:
     grad_norms: object    # jitted AdamW first moment -> first-gradient norms
     change: object        # jitted (params, key) -> norms of the change
     put: object           # host batch -> device batch
+    make_params: object   # seed key -> the weights the reference also takes
     mesh: object
     chips: int
+    pipeline: bool        # the layers run as pipeline stages
 
 
 def span(name: str):
@@ -44,8 +45,24 @@ def span(name: str):
     return jax.profiler.TraceAnnotation(name)
 
 
+@contextlib.contextmanager
+def exchange_left_out():
+    """Every ``ppermute`` traced inside delivers zeros: the pipeline's
+    exchange between stages, forward and backward, left out (a fault)."""
+    import jax
+    import jax.numpy as jnp
+
+    real = jax.lax.ppermute
+    jax.lax.ppermute = lambda x, *a, **k: jax.tree.map(jnp.zeros_like, x)
+    try:
+        yield
+    finally:
+        jax.lax.ppermute = real
+
+
 def build(mc: dict, mix: dict, reference, fault: str = "") -> Program:
-    """The program's train step for the configuration ``mc`` and the mix."""
+    """The program's train step for the configuration ``mc`` and the mix;
+    ``reference`` is the module that ``mc`` names, which knows its family."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -56,7 +73,7 @@ def build(mc: dict, mix: dict, reference, fault: str = "") -> Program:
     from repro.optim import adamw, warmup_cosine
     from repro.train.steps import TrainState, make_train_step
 
-    cfg = program_config(mc)
+    cfg = reference.program_config(mc)
     api = build_model(cfg)
     shapes = weights.flatten(jax.eval_shape(api.init, jax.random.PRNGKey(0)))
     want = weights.flatten(reference.param_shapes(mc))
@@ -102,10 +119,17 @@ def build(mc: dict, mix: dict, reference, fault: str = "") -> Program:
         def step(state, b):
             return inner(state, jax.tree.map(
                 lambda x: x[: x.shape[0] // 2], b))
+    elif fault == "no_exchange":
+        inner = step
+
+        def step(state, b):
+            with exchange_left_out():
+                return inner(state, b)
     elif fault:
         raise ValueError(f"unknown fault {fault!r}; known: {FAULTS}")
 
-    make_params = weights.make_params_fn(reference.param_shapes(mc))
+    make_params = weights.make_params_fn(reference.param_shapes(mc),
+                                         reference.NORMS)
 
     def init(key):
         params = make_params(key)
@@ -134,7 +158,8 @@ def build(mc: dict, mix: dict, reference, fault: str = "") -> Program:
             jax.tree.map(lambda x: x / (1 - b1), m))),
         change=checks.change_norms(make_params),
         put=lambda b: jax.device_put(b, batch_sh),
-        mesh=mesh, chips=mesh.devices.size)
+        make_params=make_params, mesh=mesh, chips=mesh.devices.size,
+        pipeline=pipeline)
 
 
 def checked_steps(prog: Program, state, source, n: int, key) -> tuple:
@@ -215,8 +240,7 @@ def run(ctx) -> dict:
 
     batches = [source.batch(i) for i in range(n_checked)]
     ref_readings = checks.Reference(
-        ref, mc, mix["optimizer"], ref.Numerics("float32"),
-        weights.make_params_fn(ref.param_shapes(mc)),
+        ref, mc, mix["optimizer"], ref.Numerics("float32"), prog.make_params,
         mix.get("reference_block_rows")).readings(
             key, [(b["tokens"], b["labels"]) for b in batches])
     window_s = t_end - t_start
@@ -225,7 +249,7 @@ def run(ctx) -> dict:
         "end_to_end": {"train_tokens_per_s": n * tokens_per_step / window_s,
                        "setup_s": t_start - ctx.t_process},
         "tokens_per_s": n * tokens_per_step / window_s,
-        "flops_per_token": flops.train_flops_per_token(mc, mix["seq"]),
+        "flops_per_token": ref.train_flops_per_token(mc, mix["seq"]),
         "chips": prog.chips,
         "attempted": n,
         "failed": failed,

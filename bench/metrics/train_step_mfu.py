@@ -1,6 +1,7 @@
-"""Model FLOP/s utilization of the train step: model FLOPs per token
-(``bench/flops.py``) times the traced window's tokens per second, over the
-chips' summed bf16 peak (``bench/peaks.py``), in percent."""
+"""Model FLOP/s utilization of the train step: model FLOPs per token (the
+``train_flops_per_token`` of the reference module that the configuration
+names, under ``bench/references/``) times the traced window's tokens per
+second, over the chips' summed bf16 peak (``bench/peaks.py``), in percent."""
 from bench.peaks import peak
 
 

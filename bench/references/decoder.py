@@ -11,13 +11,26 @@ out); a mixture of experts drops the assignments beyond each expert's
 capacity, ceil(tokens * k / experts * capacity_factor), in order of token
 and then of choice, and adds the Switch load-balancing loss.  Every matmul
 runs at ``Precision.HIGHEST``; ``Numerics("float8")`` rounds every matmul's
-operands to float8 e4m3 under a per-tensor scale instead, the control.
+operands to float8 e4m3 under a per-tensor scale instead, the control;
+``Numerics(cut_exchange=True)`` feeds the second half of the layers zeros
+in place of the first half's output, the fault of a two-stage pipeline
+whose exchange is left out.
 
 Memory is kept to one chip: each layer is rematerialised, attention runs
 one sequence at a time, the experts one at a time, and the LM head and loss
-one sequence at a time."""
+one sequence at a time.
+
+A reference module is the one place that knows its family, so that a new
+family is a new module and a configuration file that names it under
+``reference``.  Beside the reference's mathematics it gives the harness:
+``NORMS``, the leaves drawn as gains near one (``bench/weights.py``);
+``check_fixed`` and ``program_config``, the configuration file as the
+program's ``ModelConfig``; and ``train_flops_per_token``, the model FLOPs
+that ``train_step_mfu`` counts.  Only ``program_config`` imports the
+program, and only when it is called."""
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
@@ -27,14 +40,43 @@ from jax import lax
 HIGHEST = lax.Precision.HIGHEST
 E4M3_MAX = 448.0
 
+# leaves drawn as gains near one, each distinct, so that their gradients
+# differ; every other leaf is drawn as a matrix
+NORMS = ("ln1", "ln2", "final_norm")
+
+# Hugging Face key -> ModelConfig field
+FIELDS = {
+    "num_hidden_layers": "n_layers",
+    "hidden_size": "d_model",
+    "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads",
+    "vocab_size": "vocab_size",
+    "rms_norm_eps": "norm_eps",
+    "rope_theta": "rope_theta",
+    "num_local_experts": "n_experts",
+    "num_experts_per_tok": "experts_per_token",
+}
+# keys the program has no switch for, and the value its arithmetic implies
+FIXED = {
+    "tie_word_embeddings": False,
+    "hidden_act": "silu",
+    "attention_bias": False,
+    "mlp_bias": False,
+    "embedding_multiplier": 1.0,
+    "residual_multiplier": 1.0,
+    "logits_scaling": 1.0,
+}
+
 
 class Numerics:
-    """How matmul operands are rounded: ``float32`` (none) or ``float8``."""
+    """How matmul operands are rounded: ``float32`` (none) or ``float8``;
+    ``cut_exchange``: see the module's docstring."""
 
-    def __init__(self, mode: str = "float32"):
+    def __init__(self, mode: str = "float32", cut_exchange: bool = False):
         if mode not in ("float32", "float8"):
             raise ValueError(mode)
         self.mode = mode
+        self.cut_exchange = cut_exchange
 
     def _round(self, x):
         if self.mode == "float32":
@@ -54,9 +96,84 @@ def _dims(mc):
     return d, nh, mc["num_key_value_heads"], d // nh
 
 
+def is_moe(mc) -> bool:
+    return mc.get("num_local_experts", 0) > 0
+
+
 def padded_vocab(mc) -> int:
     m = mc["vocab_pad_multiple"]
     return -(-mc["vocab_size"] // m) * m
+
+
+def check_fixed(mc) -> None:
+    """Keys the program does not model must hold the value its arithmetic
+    implies (a multiplier of 1, untied embeddings), so that the file states
+    what is run."""
+    for k, v in FIXED.items():
+        if k in mc and mc[k] != v:
+            raise ValueError(f"{mc['name']}: {k}={mc[k]!r} is not what the "
+                             f"program runs ({v!r})")
+    if "attention_multiplier" in mc and not math.isclose(
+            mc["attention_multiplier"], _dims(mc)[3] ** -0.5):
+        raise ValueError(f"{mc['name']}: the program scales attention by "
+                         f"1/sqrt(head_dim)")
+
+
+def program_config(mc):
+    """The program's ``ModelConfig`` for configuration file ``mc``, whose
+    ``arch`` names the program's architecture id."""
+    from repro.configs import get_config
+    from repro.configs.base import VOCAB_PAD_TO
+
+    check_fixed(mc)
+    if mc["vocab_pad_multiple"] != VOCAB_PAD_TO:
+        raise ValueError("the program pads the vocabulary to a multiple of "
+                         f"{VOCAB_PAD_TO}")
+    kw = {f: mc[k] for k, f in FIELDS.items() if k in mc}
+    kw["head_dim"] = _dims(mc)[3]
+    kw["tie_embeddings"] = False
+    if is_moe(mc):
+        kw["moe_d_ff"] = kw["d_ff"] = mc["intermediate_size"]
+        kw["router_aux_loss"] = mc["router_aux_loss_coef"]
+    else:
+        kw["d_ff"] = mc["intermediate_size"]
+    cfg = dataclasses.replace(get_config(mc["arch"]), **kw)
+    if cfg.vocab_padded != padded_vocab(mc):
+        raise ValueError("padded vocabulary differs")
+    return cfg
+
+
+# Model FLOPs from the configuration's shapes: the operations that the
+# forward and backward passes require, not what a program computes (no
+# recomputation, no capacity padding, no masked-out attention, no vocabulary
+# padding).  Training counts 6 per matmul parameter touched per token (2 for
+# the forward pass, 4 for the backward), plus causal attention.
+
+def matmul_params_per_token(mc) -> int:
+    """Matmul parameters one token passes through: the attention
+    projections, the feed-forward (for a mixture of experts its router and
+    its ``num_experts_per_tok`` experts), and the LM head.  The embedding is
+    a lookup."""
+    d, nh, nkv, hd = _dims(mc)
+    ff = mc["intermediate_size"]
+    attn = 2 * d * nh * hd + 2 * d * nkv * hd
+    if is_moe(mc):
+        mlp = d * mc["num_local_experts"] + mc["num_experts_per_tok"] * 3 * d * ff
+    else:
+        mlp = 3 * d * ff
+    return mc["num_hidden_layers"] * (attn + mlp) + d * mc["vocab_size"]
+
+
+def attention_flops_per_token(mc, seq: int) -> int:
+    """Causal attention of a ``seq``-long sequence, forward and backward,
+    averaged over its tokens: (seq + 1) / 2 keys per query, 4 * heads *
+    head_dim per key forward (scores and values), times 3."""
+    _, nh, _, hd = _dims(mc)
+    return mc["num_hidden_layers"] * 6 * nh * hd * (seq + 1)
+
+
+def train_flops_per_token(mc, seq: int) -> int:
+    return 6 * matmul_params_per_token(mc) + attention_flops_per_token(mc, seq)
 
 
 def param_shapes(mc) -> dict:
@@ -163,7 +280,15 @@ def loss(num, mc, params, tokens, labels):
     def body(x, lp):
         return layer(num, mc, lp, x)
 
-    x, aux = lax.scan(body, x, params["layers"])
+    if num.cut_exchange:
+        half = mc["num_hidden_layers"] // 2
+        first, second = (jax.tree.map(lambda a: a[s], params["layers"])
+                         for s in (slice(None, half), slice(half, None)))
+        x, aux1 = lax.scan(body, x, first)
+        x, aux2 = lax.scan(body, jnp.zeros_like(x), second)
+        aux = jnp.concatenate([aux1, aux2])
+    else:
+        x, aux = lax.scan(body, x, params["layers"])
     w = params["lm_head"][:, :mc["vocab_size"]]
 
     @jax.checkpoint
@@ -203,7 +328,7 @@ def grads(num, mc, params, tokens, labels, block_rows=None):
     b = tokens.shape[0]
     if not block_rows or block_rows >= b:
         return one(params, tokens, labels)
-    if mc.get("num_local_experts", 0) or b % block_rows:
+    if is_moe(mc) or b % block_rows:
         raise ValueError("blocks of rows need a dense model and equal blocks")
     n = b // block_rows
 

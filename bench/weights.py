@@ -1,20 +1,19 @@
 """The benchmark's weights: made from the seed on the device, in one jitted
 call, in the layout a reference's ``param_shapes`` gives (the program's own
-layout, which the harness checks).  The program and the reference both take
-these weights; neither makes its own."""
+layout, which the harness checks), with the reference's ``NORMS`` drawn as
+gains.  The program and the reference both take these weights; neither
+makes its own."""
 from __future__ import annotations
 
 import zlib
 
-NORMS = ("ln1", "ln2", "final_norm")
 
-
-def _leaf_init(path: str, key, shape):
+def _leaf_init(path: str, key, shape, norms):
     import jax
     import jax.numpy as jnp
 
     name = path.rsplit("/", 1)[-1]
-    if name in NORMS:
+    if name in norms:
         # gains near one, each distinct, so that their gradients differ
         return 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
     if name == "embed":
@@ -47,8 +46,9 @@ def unflatten(flat: dict) -> dict:
     return out
 
 
-def make_params_fn(shapes: dict):
-    """``seed_key -> params`` (float32) for the nested dict of shapes."""
+def make_params_fn(shapes: dict, norms):
+    """``seed_key -> params`` (float32) for the nested dict of shapes; a
+    leaf whose last name is in ``norms`` is a gain near one."""
     import jax
 
     flat = flatten(shapes)
@@ -56,7 +56,7 @@ def make_params_fn(shapes: dict):
     def make(key):
         return unflatten({
             path: _leaf_init(path, jax.random.fold_in(
-                key, zlib.crc32(path.encode())), tuple(shape))
+                key, zlib.crc32(path.encode())), tuple(shape), norms)
             for path, shape in flat.items()})
 
     return make

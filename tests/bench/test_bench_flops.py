@@ -1,5 +1,5 @@
-"""Model FLOP counters and the table of peaks, against numbers worked out
-by hand."""
+"""Model FLOP counters (the decoder family's, in its reference module) and
+the table of peaks, against numbers worked out by hand."""
 import json
 import sys
 from pathlib import Path
@@ -9,7 +9,10 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import common, flops, peaks  # noqa: E402
+from bench import common, peaks  # noqa: E402
+
+flops = common.load_module(ROOT / "bench" / "references" / "decoder.py",
+                           "decoder")
 
 
 def config(name):

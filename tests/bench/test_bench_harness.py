@@ -104,7 +104,7 @@ def test_control_in_lower_precision_is_not_correct():
                      .read_text())
     mix.update(batch=4, seq=32)
     limits = LIMITS
-    make = weights.make_params_fn(ref.param_shapes(TINY))
+    make = weights.make_params_fn(ref.param_shapes(TINY), ref.NORMS)
     for seed in (11, 12, 13):
         src = zipf.Source(mix, TINY["vocab_size"], seed)
         pairs = [(b["tokens"], b["labels"])

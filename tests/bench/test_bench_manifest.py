@@ -7,7 +7,7 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import common, model  # noqa: E402
+from bench import common  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -58,6 +58,7 @@ def test_every_cell_finds_its_files_and_reports_what_its_metrics_move():
 
 
 def test_configs_state_their_cuts_and_cut_no_width():
+    bench = common.Bench(ROOT)
     for c in M["configs"]:
         mc = json.loads((ROOT / c["file"]).read_text())
         assert mc["name"] == c["name"] and c["file"].startswith("bench/")
@@ -65,4 +66,4 @@ def test_configs_state_their_cuts_and_cut_no_width():
         assert set(mc["source_values"]) <= set(mc["reduced"])
         assert not [k for k in c["reduced"] if WIDTH.search(k)]
         assert mc["assumed"] and mc["deployment"] and mc["source"]
-        model.check_fixed(mc)
+        bench.reference(mc["reference"]).check_fixed(mc)

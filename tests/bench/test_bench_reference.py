@@ -14,10 +14,10 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from bench import common, weights  # noqa: E402
-from bench.model import program_config  # noqa: E402
 
 ref = common.load_module(ROOT / "bench" / "references" / "decoder.py",
                          "decoder")
+program_config = ref.program_config
 zipf = common.load_module(ROOT / "bench" / "traffic" / "zipf_tokens.py",
                           "zipf_tokens")
 
@@ -54,8 +54,8 @@ def batch_of(mc, seed=0, b=4, t=32):
 def test_reference_matches_program_in_float32(mc, capacity):
     if capacity is not None:
         mc = dict(mc, capacity_factor=capacity)
-    params = jax.jit(weights.make_params_fn(ref.param_shapes(mc)))(
-        common.seed_key(7))
+    params = jax.jit(weights.make_params_fn(ref.param_shapes(mc),
+                                            ref.NORMS))(common.seed_key(7))
     batch = batch_of(mc)
     total, ce, grads, api = program_loss_and_grads(mc, params, batch)
     (r_total, r_ce), r_grads = jax.value_and_grad(
@@ -71,8 +71,8 @@ def test_reference_matches_program_in_float32(mc, capacity):
 
 
 def test_moe_capacity_drops_change_the_loss():
-    params = jax.jit(weights.make_params_fn(ref.param_shapes(MOE)))(
-        common.seed_key(3))
+    params = jax.jit(weights.make_params_fn(ref.param_shapes(MOE),
+                                            ref.NORMS))(common.seed_key(3))
     batch = batch_of(MOE, 1)
     losses = [float(ref.loss(ref.Numerics(), dict(MOE, capacity_factor=cf),
                              params, batch["tokens"], batch["labels"])[1])
@@ -91,12 +91,35 @@ def test_layout_matches_the_program():
 
 
 def test_float8_control_differs_from_float32():
-    params = jax.jit(weights.make_params_fn(ref.param_shapes(DENSE)))(
-        common.seed_key(5))
+    params = jax.jit(weights.make_params_fn(ref.param_shapes(DENSE),
+                                            ref.NORMS))(common.seed_key(5))
     batch = batch_of(DENSE, 2)
     a, b = (float(ref.loss(ref.Numerics(m), DENSE, params, batch["tokens"],
                            batch["labels"])[1]) for m in ("float32", "float8"))
     assert a != b and abs(a - b) / a < 0.1
+
+
+def test_cut_exchange_fault_feeds_the_second_half_zeros():
+    """Two stages with no exchange: the second half of the layers starts
+    from zeros, which no layer without biases moves, so the logits are zero
+    (the loss is log(vocab)) and no gradient is left."""
+    params = jax.jit(weights.make_params_fn(ref.param_shapes(DENSE),
+                                            ref.NORMS))(common.seed_key(6))
+    batch = batch_of(DENSE, 3)
+    read = {}
+    for cut in (False, True):
+        read[cut] = jax.value_and_grad(
+            lambda p: ref.loss(ref.Numerics("float32", cut_exchange=cut),
+                               DENSE, p, batch["tokens"], batch["labels"]),
+            has_aux=True)(params)
+    (_, ce), g = read[False]
+    (_, ce_cut), g_cut = read[True]
+    assert float(ce_cut) == pytest.approx(np.log(DENSE["vocab_size"]))
+    assert float(ce) != float(ce_cut)
+    assert all(float(jnp.max(jnp.abs(x))) == 0.0
+               for x in weights.flatten(g_cut).values())
+    assert all(float(jnp.max(jnp.abs(x))) > 0.0
+               for x in weights.flatten(g).values())
 
 
 def test_learning_rate_matches_the_program_schedule():
@@ -109,7 +132,8 @@ def test_learning_rate_matches_the_program_schedule():
 
 
 def test_weights_are_made_from_the_seed():
-    make = jax.jit(weights.make_params_fn(ref.param_shapes(DENSE)))
+    make = jax.jit(weights.make_params_fn(ref.param_shapes(DENSE),
+                                          ref.NORMS))
     a, b, c = (weights.flatten(make(common.seed_key(s)))
                for s in (2**33 + 1, 2**33 + 1, 1))
     for k in a:
