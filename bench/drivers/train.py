@@ -8,7 +8,12 @@ compile it and are read for the comparison with the reference.  The same
 step and state then run the window: each step's loss is fetched, as the
 training loop does, and the window ends at the first step that completes
 ``seconds`` after it began.  After the window the state is freed and the
-reference trains from the same weights on the same batches."""
+reference trains from the same weights on the same batches.
+
+A traced run also reads the window's device time by the program's layer
+scopes, the runs of each scope's kernels and the host causes of the longest
+idle gaps (``bench/scopes.py``), from the step's HLO text, which it takes
+after the window from the shapes of the state and batch."""
 from __future__ import annotations
 
 import contextlib
@@ -19,7 +24,7 @@ import shutil
 import tempfile
 import time
 
-from bench import checks, trace, weights
+from bench import checks, scopes, trace, weights
 from bench.common import seed_key
 
 WINDOW, STEP, FEED, FETCH = ("bench.window", "bench.step", "bench.feed",
@@ -188,6 +193,27 @@ def free(tree) -> None:
             x.delete()
 
 
+def layers(events, hlo: str) -> dict:
+    """From a traced window: ``scopes``, each layer scope's share of the
+    window (mean over devices) and the unscoped share, None for a step that
+    carries no scope; ``scopes_window_s``, the window's length;
+    ``kernel_runs``, each scope's kernel runs by kernel and direction; and
+    ``gap_causes``."""
+    ops, names = scopes.op_names(hlo), scopes.names()
+    red = scopes.reduce(events, ops, names)
+    shares = window_s = None
+    if red is not None:
+        shares = dict(red["scopes"], **{scopes.UNSCOPED: red[scopes.UNSCOPED]})
+        window_s = red["window_s"]
+    return {
+        "scopes": shares,
+        "scopes_window_s": window_s,
+        "kernel_runs": scopes.kernel_runs(events, ops, scopes.kernels(hlo),
+                                          names),
+        "gap_causes": scopes.gap_causes(events),
+    }
+
+
 def run(ctx) -> dict:
     import jax
 
@@ -232,11 +258,17 @@ def run(ctx) -> dict:
         gc.unfreeze()
         compiled_in_window = prog.step._cache_size() - compiled
     peak = memory_peak(prog.mesh.devices.flat)
+    shapes = scopes.abstract((state, nxt)) if logdir else None
     free((state, nxt))
-    reduced = None
+    reduced, by_layer = None, {}
     if logdir:
-        reduced = trace.reduce(trace.load(logdir))
+        events = trace.load(logdir)
         shutil.rmtree(logdir, ignore_errors=True)
+        reduced = trace.reduce(events)
+        with jax.set_mesh(prog.mesh):
+            hlo = scopes.step_hlo(prog, *shapes)
+        by_layer = layers(events, hlo)
+        del events
 
     batches = [source.batch(i) for i in range(n_checked)]
     ref_readings = checks.Reference(
@@ -249,6 +281,7 @@ def run(ctx) -> dict:
         "end_to_end": {"train_tokens_per_s": n * tokens_per_step / window_s,
                        "setup_s": t_start - ctx.t_process},
         "tokens_per_s": n * tokens_per_step / window_s,
+        "tokens_per_step": tokens_per_step,
         "flops_per_token": ref.train_flops_per_token(mc, mix["seq"]),
         "chips": prog.chips,
         "attempted": n,
@@ -257,6 +290,10 @@ def run(ctx) -> dict:
         "step_s": [b - a for a, b in zip([t_start] + ends, ends)],
         "memory_peak_bytes": peak,
         "trace": reduced,
+        **by_layer,
+        "attention_kernel": (ref.attention_kernel_cost(mc, mix["seq"])
+                             if hasattr(ref, "attention_kernel_cost")
+                             else None),
         "numbers": checks.compare(prog_readings, ref_readings),
         "readings": {"program": prog_readings, "reference": ref_readings},
     }

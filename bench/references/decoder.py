@@ -25,8 +25,10 @@ family is a new module and a configuration file that names it under
 ``reference``.  Beside the reference's mathematics it gives the harness:
 ``NORMS``, the leaves drawn as gains near one (``bench/weights.py``);
 ``check_fixed`` and ``program_config``, the configuration file as the
-program's ``ModelConfig``; and ``train_flops_per_token``, the model FLOPs
-that ``train_step_mfu`` counts.  Only ``program_config`` imports the
+program's ``ModelConfig``; ``train_flops_per_token``, the model FLOPs
+that ``train_step_mfu`` counts; and ``attention_kernel_cost``, the
+operations and bytes of the attention kernel's calls that
+``train_attention_roofline`` counts.  Only ``program_config`` imports the
 program, and only when it is called."""
 from __future__ import annotations
 
@@ -170,6 +172,29 @@ def attention_flops_per_token(mc, seq: int) -> int:
     head_dim per key forward (scores and values), times 3."""
     _, nh, _, hd = _dims(mc)
     return mc["num_hidden_layers"] * 6 * nh * hd * (seq + 1)
+
+
+def attention_kernel_cost(mc, seq: int) -> dict:
+    """Operations and HBM bytes a token needs, over all layers, in each kind
+    of call of a flash attention kernel over a causal ``seq``-long sequence:
+    ``forward`` computes the scores and the weighted sum of values (two
+    matmuls over (seq + 1) / 2 keys a query) and writes each row's
+    log-sum-exp; ``backward``, one fused call, recomputes the scores and
+    computes dP, dV, dK and dQ (five matmuls).  Each call reads and writes
+    each operand once: bfloat16 q, k, v, o, dO, dQ, dK, dV and float32 row
+    statistics (the log-sum-exp, and in the backward rowsum(dO * O) too).
+    How many forward calls a backward pass takes (one, or two with the
+    layer rematerialised) is read from the trace, not assumed here."""
+    _, nh, nkv, hd = _dims(mc)
+    L, q, kv = mc["num_hidden_layers"], nh * hd, nkv * hd
+    matmul = nh * hd * (seq + 1)        # 2 * hd * (seq + 1) / 2 per head
+    return {
+        "forward": {"flops": L * 2 * matmul,
+                    "bytes": L * (2 * (q + 2 * kv + q) + 4 * nh)},
+        "backward": {"flops": L * 5 * matmul,
+                     "bytes": L * (2 * (q + 2 * kv + q + q + 2 * kv)
+                                   + 4 * 2 * nh)},
+    }
 
 
 def train_flops_per_token(mc, seq: int) -> int:

@@ -117,6 +117,9 @@ def main(argv=None, *, root=common.ROOT, require_chip: bool = True,
           f"leaves {record['numbers'].get('leaves')} step_s "
           f"{[round(t, 4) for t in record.get('step_s', [])]}",
           file=sys.stderr)
+    for key in ("scopes", "kernel_runs", "gap_causes"):
+        if record.get(key):
+            print(f"[bench] {key} {json.dumps(record[key])}", file=sys.stderr)
     for name, c in out["checks"].items():
         print(f"check {name} {c['value']!r} limit {c['limit']!r}",
               file=sys.stderr)

@@ -11,6 +11,9 @@ union of each scope's intervals within the ``bench.window`` span:
   the instruction name the trace gives the op.  A fusion without metadata of
   its own takes the ``op_name``s of the instructions it fuses, joined by
   ``;`` as XLA joins them;
+- the compiled text is read with each instruction whole: an attribute
+  that spans several lines, as a Mosaic call's ``kernel_metadata`` does,
+  is joined onto its instruction (``join_lines``);
 - the name is split on ``;`` and each part on ``/``; ``jvp(`` and
   ``transpose(`` wrappers and closing ``)`` are stripped; the deepest
   component that is a scope wins, and an op with none is unscoped;
@@ -23,18 +26,25 @@ union of each scope's intervals within the ``bench.window`` span:
   unscoped share and the idle share sum to 1, apart from scoped ops that
   overlap each other.
 
+``kernel_runs`` counts, for each scope, the runs of its Mosaic kernels
+(``tpu_custom_call`` instructions) in the window, by kernel and by
+direction: a run whose ``op_name`` lies under ``transpose(`` and outside a
+``rematted_computation`` belongs to the backward pass, every other run to a
+forward pass (the first or the rematerialised one).
+
+``bench/drivers/train.py`` reduces every traced run's window this way,
+for the per-layer metrics that read a scope, and records ``gap_causes``
+beside it.
+
 ``gap_causes`` names, for each of the longest idle gaps, the host-runtime
 event that covers most of it: an event of a runtime thread of ``/host:CPU``
 (a PjRt execute, allocation or transfer), not of the Python thread, where
 the harness's spans and JAX's Python-level annotations lie.
 
-    python3 bench/scopes.py --workload <cell> --seed <n> [--steps <k>]
+    python3 bench/scopes.py --workload <cell> --same-program
 
-runs the cell's train step as the benchmark builds it, traces ``k`` steps
-inside a ``bench.window`` span, and prints the table to standard error and
-one JSON line to standard output.  ``--same-program`` instead compiles the
-step twice, with the scopes and with each scope a no-op, and reports
-whether the two programs differ apart from metadata."""
+compiles the cell's step twice, with the scopes and with each scope a
+no-op, and reports whether the two programs differ apart from metadata."""
 from __future__ import annotations
 
 import argparse
@@ -42,9 +52,7 @@ import collections
 import contextlib
 import json
 import re
-import shutil
 import sys
-import tempfile
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -63,6 +71,10 @@ INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
 OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 CALLS = re.compile(r"calls=%?([\w.\-]+)")
 METADATA = re.compile(r",? metadata=\{[^}]*\}")
+MOSAIC = 'custom_call_target="tpu_custom_call"'
+NUMBERED = re.compile(r"\.\d+$")
+BACKWARD = "transpose("
+REMAT = "rematted_computation"
 
 
 def names() -> tuple:
@@ -75,11 +87,28 @@ def names() -> tuple:
     return tuple(NAMES)
 
 
+def join_lines(hlo_text: str) -> list:
+    """The text's lines, each instruction whole: an attribute that spans
+    several lines (a Mosaic call's ``kernel_metadata``) continues on lines
+    that start with neither a space nor ``HloModule``, and that are neither
+    a computation's header nor its closing ``}``; they are joined onto the
+    line before."""
+    out = []
+    for line in hlo_text.splitlines():
+        if (out and line and line != "}"
+                and not line.startswith((" ", "HloModule"))
+                and not COMPUTATION.match(line)):
+            out[-1] += line
+        else:
+            out.append(line)
+    return out
+
+
 def op_names(hlo_text: str) -> dict:
     """Instruction name -> ``op_name`` for every instruction of an HLO
     module's text ("" where neither it nor what it fuses has one)."""
     comps, cur = {}, None
-    for line in hlo_text.splitlines():
+    for line in join_lines(hlo_text):
         if not line.startswith((" ", "HloModule")):
             m = COMPUTATION.match(line)
             cur = comps.setdefault(m.group(1), []) if m else None
@@ -99,6 +128,12 @@ def op_names(hlo_text: str) -> dict:
                     o for _, o, _ in comps[callee] if o))
             out[name] = own
     return out
+
+
+def kernels(hlo_text: str) -> set:
+    """Names of the instructions that call a Mosaic kernel."""
+    return {INSTRUCTION.match(ln).group(1) for ln in join_lines(hlo_text)
+            if MOSAIC in ln and INSTRUCTION.match(ln)}
 
 
 def scope_of(op_name: str, scopes: tuple) -> str:
@@ -179,6 +214,31 @@ def reduce(events, hlo_ops: dict, scopes: tuple) -> dict | None:
     }
 
 
+def kernel_runs(events, hlo_ops: dict, calls: set, scopes: tuple) -> dict:
+    """Scope -> {"forward": {kernel: runs}, "backward": {kernel: runs}}: the
+    runs in the ``bench.window`` span, summed over devices, of each kernel
+    call (an instruction of ``calls``) under the scope, by kernel (the
+    instruction's name without its number) and direction."""
+    devices = device_ops(events)
+    if not devices:
+        return {}
+    lo, hi = window(events, devices)
+    out = {}
+    for evs in devices.values():
+        for e in leaves(evs):
+            name = instruction(e.name)
+            if name not in calls or not lo <= e.start < hi:
+                continue
+            op = hlo_ops.get(name, "")
+            s = scope_of(op, scopes)
+            way = ("backward" if BACKWARD in op and REMAT not in op
+                   else "forward")
+            runs = out.setdefault(s, {"forward": {}, "backward": {}})[way]
+            kernel = NUMBERED.sub("", name)
+            runs[kernel] = runs.get(kernel, 0) + 1
+    return out
+
+
 def device_ops(events) -> dict:
     """Device index -> the events of its ops line."""
     devices = collections.defaultdict(list)
@@ -240,17 +300,6 @@ def strip_metadata(hlo_text: str) -> str:
     return METADATA.sub("", hlo_text)
 
 
-def table(red: dict) -> str:
-    lines = [f"{'scope':<14} {'share %':>8}"]
-    lines += [f"{s:<14} {100 * v:8.3f}" for s, v in red["scopes"].items()]
-    lines += [f"{UNSCOPED:<14} {100 * red[UNSCOPED]:8.3f}",
-              f"{'idle':<14} {100 * red['idle']:8.3f}",
-              f"(ops not in the HLO text: {red['unmatched_s']:.6f} s)"]
-    lines += [f"  unscoped op {t:.6f} s  {op[:160]}"
-              for op, t in red["unscoped_ops"]]
-    return "\n".join(lines)
-
-
 # --- running a cell ---------------------------------------------------------
 
 def abstract(tree):
@@ -298,9 +347,7 @@ def same_program(build, mc, mix, ref) -> dict:
 def parse(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--workload", required=True)
-    ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--steps", type=int, default=5)
-    ap.add_argument("--same-program", action="store_true")
+    ap.add_argument("--same-program", action="store_true", required=True)
     return ap.parse_args(argv)
 
 
@@ -316,48 +363,10 @@ def main(argv=None, *, root=common.ROOT, require_chip: bool = True) -> dict:
                          f"{jax.devices()[0].platform}")
     driver = bench.driver(mix["driver"])
     ref = bench.reference(mc["reference"])
-    if args.same_program:
-        # a cached executable would hide a difference
-        jax.config.update("jax_enable_compilation_cache", False)
-        out = {"workload": cell["name"],
-               "same_program": same_program(driver.build, mc, mix, ref)}
-        print(json.dumps(out), flush=True)
-        return out
-    common.enable_compile_cache(root)
-    prog = driver.build(mc, mix, ref)
-    source = bench.generator(mix["kind"]).Source(mix, mc["vocab_size"],
-                                                 args.seed)
-    logdir = tempfile.mkdtemp(prefix="bench_scopes_")
-    with jax.set_mesh(prog.mesh):
-        state = prog.init(common.seed_key(args.seed))
-        nxt = prog.put(source.batch(0))
-        for i in range(2):      # compile and warm up outside the trace
-            state, metrics = prog.step(state, nxt)
-            nxt = prog.put(source.batch(i + 1))
-            float(metrics["loss"])
-        with trace.capture(logdir), driver.span(driver.WINDOW):
-            for i in range(args.steps):
-                with driver.span(driver.STEP):
-                    state, metrics = prog.step(state, nxt)
-                with driver.span(driver.FEED):
-                    nxt = prog.put(source.batch(i + 3))
-                with driver.span(driver.FETCH):
-                    float(metrics["loss"])
-        hlo = step_hlo(prog, state, nxt)
-    events = trace.load(logdir)
-    shutil.rmtree(logdir, ignore_errors=True)
-    red = reduce(events, op_names(hlo), names())
-    gaps = gap_causes(events)
-    print(f"[scopes] {cell['name']} seed={args.seed} steps={args.steps}",
-          file=sys.stderr)
-    if red is not None:
-        print(table(red), file=sys.stderr)
-    for g in gaps:
-        print(f"  idle gap {g[0]:.6f} s: host event {g[1]!r} covers "
-              f"{g[2]:.6f} s, harness span {g[3]}", file=sys.stderr)
-    out = {"workload": cell["name"], "device": jax.devices()[0].device_kind,
-           "scopes": red, "gap_causes": gaps,
-           "busy_s": trace.reduce(events)["busy_s"]}
+    # a cached executable would hide a difference
+    jax.config.update("jax_enable_compilation_cache", False)
+    out = {"workload": cell["name"],
+           "same_program": same_program(driver.build, mc, mix, ref)}
     print(json.dumps(out), flush=True)
     return out
 

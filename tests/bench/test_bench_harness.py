@@ -123,6 +123,65 @@ def test_traced_run_on_the_cpu_has_no_device_trace(root):
         run_tiny(root, trace=1)
 
 
+def test_traced_run_reads_the_layer_scopes(root, monkeypatch):
+    """A traced run on the CPU, with the profiler's events replaced by
+    events built by hand on the instructions of the step that the run
+    compiles, two of attention's taken for kernel calls (the CPU step calls
+    none): a forward that runs twice and a backward that runs once.  The
+    scope shares and the kernel runs reach the per-layer metrics that read
+    them."""
+    import jax
+
+    from bench import peaks, scopes, trace
+    bench = common.Bench(root)
+    driver, ref = bench.driver("train"), bench.reference("decoder")
+    mix = bench.traffic("train.tiny")
+    prog = driver.build(TINY, mix, ref)
+    source = bench.generator(mix["kind"]).Source(mix, TINY["vocab_size"], 0)
+    state = prog.init(common.seed_key(0))
+    with jax.set_mesh(prog.mesh):
+        ops = scopes.op_names(scopes.step_hlo(prog, state, prog.put(
+            source.batch(0))))
+    by_scope = {}
+    for name, op in sorted(ops.items()):
+        by_scope.setdefault(scopes.scope_of(op, scopes.names()), name)
+    core = [n for n, op in sorted(ops.items())
+            if scopes.scope_of(op, scopes.names()) == "attn.core"]
+    fwd = next(n for n in core if "transpose(" not in ops[n])
+    bwd = next(n for n in core if "transpose(" in ops[n]
+               and "rematted_computation" not in ops[n])
+    monkeypatch.setattr(scopes, "kernels", lambda hlo: {fwd, bwd})
+    ms = 1e6
+    # window 0..100 ms: attention 0-30 (a forward twice, then a backward),
+    # the MLP 30-50, the optimizer 50-60
+    events = [trace.Event("/host:CPU", "python", "bench.window", 0, 100 * ms)]
+    for name, start, dur in ((fwd, 0, 10), (fwd, 10, 10), (bwd, 20, 10),
+                             (by_scope["mlp"], 30, 20),
+                             (by_scope["optim"], 50, 10)):
+        events.append(trace.Event("/device:TPU:0", "XLA Ops", name,
+                                  start * ms, dur * ms))
+    monkeypatch.setattr(trace, "load", lambda logdir: events)
+    monkeypatch.setitem(peaks.PEAKS, jax.devices()[0].device_kind,
+                        {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e9})
+    out = run_tiny(root, trace=1)
+    m = {k: v["value"] for k, v in out["metrics"].items()}
+    assert set(m) == {"train_step_mfu", "device_idle_share.train",
+                      "attention_core_share.train",
+                      "train_attention_roofline"}
+    assert m["attention_core_share.train"] == pytest.approx(30.0)
+    assert m["device_idle_share.train"] == pytest.approx(40.0)
+    # the window's tokens through two forwards and a backward, at the least
+    # time each call could take, over attention's 30 ms
+    cost = ref.attention_kernel_cost(TINY, mix["seq"])
+    least = {k: max(c["flops"] / 1e12, c["bytes"] / 1e9)
+             for k, c in cost.items()}
+    tokens = out["attempted"] * mix["batch"] * mix["seq"]
+    assert m["train_attention_roofline"] == pytest.approx(
+        100 * tokens * (2 * least["forward"] + least["backward"]) / 0.03)
+    assert out["device"]["busy_s"] == pytest.approx(0.06)
+    assert out["correct"] is True
+
+
 def _env():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("PYTHONPATH", None)

@@ -102,6 +102,135 @@ def test_op_names_read_metadata_and_what_a_fusion_fuses():
     assert scopes.instruction("fusion.1") == "fusion.1"
 
 
+# A Mosaic call's ``kernel_metadata`` spans three lines of the compiled
+# text, as the TPU compiler prints the splash kernel's calls; the rest of the
+# layer's body follows it, and another computation after that
+MOSAIC = f"""HloModule jit_train_step, entry_computation_layout={{(bf16[2,128,64]{{2,1,0}})->f32[]}}
+
+%body.3 (q.1: bf16[2,128,64]) -> f32[2,128] {{
+  %q.1 = bf16[2,128,64]{{2,1,0}} parameter(0)
+  %splash_mha_fwd_residuals.1 = (f32[128,128]{{1,0}}, bf16[2,128,64]{{2,1,0}}) custom-call(%q.1, %q.1, %q.1), custom_call_target="tpu_custom_call", frontend_attributes={{kernel_metadata={{
+"xprof_metadata":"{{\"block_q\": 1024, \"block_kv\": 1024, \"use_fused_bwd_kernel\": true}}"
+}}}}, metadata={{op_name="{S}/jvp()/while/body/closed_call/attn.proj/jvp(attn.core)/vmap(jit(_splash_attention))/splash_mha_fwd_residuals/pallas_call" stack_frame_id=13}}, backend_config={{"custom_call_config":{{"body":"TUzvUgFNTElS"}}}}
+  %pallas_call.2 = bf16[2,128,64]{{2,1,0}} get-tuple-element(%splash_mha_fwd_residuals.1), index=1, frontend_attributes={{kernel_metadata={{
+"xprof_metadata":"{{\"block_q\": 1024}}"
+}}}}, metadata={{op_name="{S}/jvp()/while/body/closed_call/attn.proj/jvp(attn.core)/vmap(jit(_splash_attention))/splash_mha_fwd_residuals/pallas_call"}}
+  %multiply_reduce_fusion = f32[2,128]{{1,0}} fusion(%pallas_call.2), kind=kLoop, calls=%fused_computation.4, metadata={{op_name="{S}/transpose(jvp())/while/body/closed_call/attn.proj/transpose(jvp(attn.core))/dot_general"}}
+  ROOT %convert.7 = f32[2,128]{{1,0}} convert(%multiply_reduce_fusion), metadata={{op_name="{S}/jvp()/while/body/closed_call/attn.proj/convert_element_type"}}
+}}
+
+%fused_computation.4 (param_0.5: bf16[2,128,64]) -> f32[2,128] {{
+  %param_0.5 = bf16[2,128,64]{{2,1,0}} parameter(0)
+  ROOT %reduce.6 = f32[2,128]{{1,0}} reduce(%param_0.5), dimensions={{2}}
+}}
+
+ENTRY %main.8 (q: bf16[2,128,64]) -> f32[] {{
+  %q = bf16[2,128,64]{{2,1,0}} parameter(0)
+  %call.9 = f32[2,128]{{1,0}} call(%q), to_apply=%body.3
+  ROOT %reduce.10 = f32[] reduce(%call.9), dimensions={{0,1}}, metadata={{op_name="{S}/transpose(jvp(loss))/loss/reduce_sum"}}
+}}
+"""
+
+
+def test_join_lines_keeps_a_multi_line_attribute_in_its_instruction():
+    lines = scopes.join_lines(MOSAIC)
+    assert not [ln for ln in lines if ln.startswith(('"', "}}"))]
+    assert lines.count("}") == 3
+    assert sum(1 for ln in lines if scopes.COMPUTATION.match(ln)) == 3
+    assert scopes.join_lines(HLO) == HLO.splitlines()
+
+
+def test_op_names_read_past_a_mosaic_call():
+    ops = scopes.op_names(MOSAIC)
+    # the call's own name lies on the last line of its metadata
+    for name in ("splash_mha_fwd_residuals.1", "pallas_call.2"):
+        assert ops[name].endswith("splash_mha_fwd_residuals/pallas_call")
+        assert scopes.scope_of(ops[name], NAMES) == "attn.core"
+    # the rest of the layer's body, after the call
+    assert scopes.scope_of(ops["multiply_reduce_fusion"], NAMES) == "attn.core"
+    assert scopes.scope_of(ops["convert.7"], NAMES) == "attn.proj"
+    # and the computations after it
+    assert scopes.scope_of(ops["call.9"], NAMES) == "unscoped"
+    assert scopes.scope_of(ops["reduce.10"], NAMES) == "loss"
+    assert set(ops) == {"q.1", "splash_mha_fwd_residuals.1", "pallas_call.2",
+                        "multiply_reduce_fusion", "convert.7", "param_0.5",
+                        "reduce.6", "q", "call.9", "reduce.10"}
+
+
+def test_reduce_shares_past_a_mosaic_call():
+    # window 0..100 ms: the splash call 0-40 and the fusion reading its
+    # output 40-50 (attn.core), the convert 50-70 (attn.proj), the loss
+    # 70-80, idle 80-100
+    evs = [host("bench.window", 0, 100),
+           dev(0, "%splash_mha_fwd_residuals.1 = (f32[128,128]) custom-call()",
+               0, 40),
+           dev(0, "multiply_reduce_fusion", 40, 10),
+           dev(0, "convert.7", 50, 20), dev(0, "reduce.10", 70, 10)]
+    red = scopes.reduce(evs, scopes.op_names(MOSAIC), NAMES)
+    assert red["scopes"] == {"attn.core": pytest.approx(0.5),
+                             "attn.proj": pytest.approx(0.2),
+                             "loss": pytest.approx(0.1)}
+    assert red["unscoped"] == pytest.approx(0.0)
+    assert red["idle"] == pytest.approx(0.2)
+    assert red["unmatched_s"] == 0.0
+
+
+# The splash kernel's three calls in a layer of a rematerialised train step,
+# with the op_names the TPU compiler gives them: the forward, the forward
+# again inside the backward's rematerialised computation, and the fused
+# backward; and the pipeline's plain forward, outside any jvp
+SPLASH = "attn.proj/attn.core/cond/branch_0_fun/vmap(jit(_splash_attention))"
+KERNELS = f"""HloModule jit_train_step, entry_computation_layout={{(bf16[8,15,64,2048]{{3,2,1,0}})->f32[]}}
+
+ENTRY %main.1 (q: bf16[8,15,64,2048]) -> f32[] {{
+  %q = bf16[8,15,64,2048]{{3,2,1,0}} parameter(0)
+  %splash_mha_fwd_residuals.19 = (bf16[8,15,2048,64]{{3,2,1,0}}) custom-call(%q), custom_call_target="tpu_custom_call", metadata={{op_name="{S}/jvp()/while/body/closed_call/{SPLASH}/splash_mha_fwd_residuals/pallas_call"}}
+  %splash_mha_fwd_residuals.18 = (bf16[8,15,2048,64]{{3,2,1,0}}) custom-call(%q), custom_call_target="tpu_custom_call", frontend_attributes={{kernel_metadata={{
+"xprof_metadata":"{{\"block_q\": 1024}}"
+}}}}, metadata={{op_name="{S}/transpose(jvp())/while/body/closed_call/checkpoint/rematted_computation/{SPLASH}/splash_mha_fwd_residuals/pallas_call"}}
+  %splash_mha_dkv_no_residuals.9 = (bf16[8,15,2048,64]{{3,2,1,0}}) custom-call(%q), custom_call_target="tpu_custom_call", metadata={{op_name="{S}/transpose(jvp())/while/body/closed_call/checkpoint/{SPLASH}/splash_mha_dkv_no_residuals/pallas_call"}}
+  %splash_mha_fwd_no_residuals.6 = (bf16[8,15,2048,64]{{3,2,1,0}}) custom-call(%q), custom_call_target="tpu_custom_call", metadata={{op_name="{S}/shard_map/while/body/closed_call/checkpoint/{SPLASH}/splash_mha_fwd_no_residuals/pallas_call"}}
+  %custom-call.3 = s32[16384]{{0}} custom-call(%q), custom_call_target="AssumeGatherIndicesInBound", metadata={{op_name="{S}/jvp(embed)/gather"}}
+  %fusion.7 = f32[8,2048]{{1,0}} fusion(%q), kind=kLoop, calls=%fused_computation.8, metadata={{op_name="{S}/transpose(jvp())/while/body/closed_call/checkpoint/{SPLASH}/mul"}}
+  ROOT %reduce.10 = f32[] reduce(%fusion.7), dimensions={{0,1}}, metadata={{op_name="{S}/transpose(jvp(loss))/loss/reduce_sum"}}
+}}
+"""
+
+
+def test_kernels_are_the_mosaic_calls():
+    assert scopes.kernels(KERNELS) == {
+        "splash_mha_fwd_residuals.19", "splash_mha_fwd_residuals.18",
+        "splash_mha_dkv_no_residuals.9", "splash_mha_fwd_no_residuals.6"}
+    assert scopes.kernels(MOSAIC) == {"splash_mha_fwd_residuals.1"}
+    assert scopes.kernels(HLO) == set()
+
+
+def test_kernel_runs_by_kernel_and_direction():
+    # window 0..100 ms on two devices; each runs a layer's forward,
+    # rematerialised forward and backward, and the first a pipeline's plain
+    # forward; runs of no time, after the window, or of no kernel, and
+    # the backward's fusion, are not counted
+    evs = [host("bench.window", 0, 100)]
+    for i in (0, 1):
+        evs += [dev(i, "splash_mha_fwd_residuals.19", 0, 10),
+                dev(i, "%splash_mha_fwd_residuals.18 = (bf16[8,15,2048,64]) "
+                       "custom-call(%q)", 20, 10),
+                dev(i, "splash_mha_dkv_no_residuals.9", 30, 15),
+                dev(i, "fusion.7", 45, 5), dev(i, "custom-call.3", 50, 1),
+                dev(i, "splash_mha_dkv_no_residuals.9", 60, 0),
+                dev(i, "splash_mha_dkv_no_residuals.9", 120, 10)]
+    evs.append(dev(0, "splash_mha_fwd_no_residuals.6", 70, 5))
+    ops = scopes.op_names(KERNELS)
+    got = scopes.kernel_runs(evs, ops, scopes.kernels(KERNELS), NAMES)
+    assert got == {"attn.core": {
+        "forward": {"splash_mha_fwd_residuals": 4,
+                    "splash_mha_fwd_no_residuals": 1},
+        "backward": {"splash_mha_dkv_no_residuals": 2}}}
+    assert scopes.kernel_runs(evs, ops, set(), NAMES) == {}
+    assert scopes.kernel_runs([host("bench.window", 0, 1)], ops,
+                              scopes.kernels(KERNELS), NAMES) == {}
+
+
 def test_strip_metadata():
     stripped = scopes.strip_metadata(HLO)
     assert "metadata" not in stripped and "op_name" not in stripped
